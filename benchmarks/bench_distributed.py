@@ -42,7 +42,7 @@ STEP_SCRIPT = textwrap.dedent(
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.core import ContrastiveConfig, RetrievalBatch, get_shard_map
+    from repro.core import ContrastiveConfig, RetrievalBatch
     from repro.core.methods import build_step_program, init_state
     from repro.distribution.sharding import contrastive_state_spec
     from repro.models.bert import BertConfig
@@ -53,7 +53,6 @@ STEP_SCRIPT = textwrap.dedent(
     D = 8
     assert jax.device_count() == D, jax.device_count()
     mesh = Mesh(np.array(jax.devices()), ("data",))
-    shard_map, sm_kw = get_shard_map()
 
     enc = make_bert_dual_encoder(BertConfig(
         name="bench-bert", n_layers=2, d_model=64, n_heads=4, d_ff=128,
@@ -93,9 +92,9 @@ STEP_SCRIPT = textwrap.dedent(
         spec = contrastive_state_spec(("data",), shard_banks)
         bspec = RetrievalBatch(query=P("data"), passage_pos=P("data"),
                                passage_hard=None)
-        update = jax.jit(shard_map(
+        update = jax.jit(jax.shard_map(
             build_step_program(enc, tx, cfg).update, mesh=mesh,
-            in_specs=(spec, bspec), out_specs=(spec, P()), **sm_kw,
+            in_specs=(spec, bspec), out_specs=(spec, P()), check_vma=False,
         ))
         for i in range(warmup):
             state, m = update(state, make_batch(i))
@@ -143,7 +142,6 @@ TRANSIENT_SCRIPT = textwrap.dedent(
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.core import get_shard_map
     from repro.core.dist import DistCtx
     from repro.core.loss import (
         FusedLossBackend, contrastive_loss, sharded_bank_extra_columns,
@@ -153,7 +151,6 @@ TRANSIENT_SCRIPT = textwrap.dedent(
     N_MEM, REP_D, B_LOCAL = 2048, 64, 8
     assert jax.device_count() == D, jax.device_count()
     mesh = Mesh(np.array(jax.devices()), ("data",))
-    shard_map, sm_kw = get_shard_map()
     ctx = DistCtx(("data",))
     backend = FusedLossBackend(interpret=True)
 
@@ -185,10 +182,10 @@ TRANSIENT_SCRIPT = textwrap.dedent(
             return f(q), q
 
         row = P("data")
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             eval_loss, mesh=mesh,
             in_specs=(row, row, row, row, row, P()),
-            out_specs=(P(), row), **sm_kw,
+            out_specs=(P(), row), check_vma=False,
         ))
 
     for grad in (False, True):
@@ -215,6 +212,8 @@ def _subprocess_rows(argv, timeout=1200) -> List[Tuple[str, float]]:
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
     env.pop("XLA_FLAGS", None)
+    # a CPU-harness child: on a chip host the parent already holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         argv,
         capture_output=True,

@@ -51,7 +51,7 @@ from repro.core.types import ContrastiveConfig, RetrievalBatch
 from repro.data.loader import MinedNegativeInjector, ShardedLoader
 from repro.data.retrieval import SyntheticRetrievalCorpus
 from repro.evaluation import evaluate_topk
-from repro.launch.train import tiny_bert
+from repro.models.bert import tiny_bert
 from repro.mining import HardNegativeMiner, MinerConfig
 from repro.models.towers import make_bert_dual_encoder
 from repro.optim import adamw, chain, clip_by_global_norm

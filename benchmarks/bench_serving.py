@@ -39,7 +39,7 @@ SCRIPT = textwrap.dedent(
     import numpy as np
 
     from repro.data.retrieval import SyntheticRetrievalCorpus
-    from repro.launch.train import tiny_bert
+    from repro.models.bert import tiny_bert
     from repro.models.towers import make_bert_dual_encoder
     from repro.retrieval import (
         Retriever, RetrieverConfig, make_dp_mesh, make_server,
@@ -116,6 +116,8 @@ def run(quick: bool = False) -> List[Tuple[str, float]]:
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
     env.pop("XLA_FLAGS", None)
+    # a CPU-harness child: on a chip host the parent already holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     argv = [sys.executable, "-c", SCRIPT] + (["--quick"] if quick else [])
     proc = subprocess.run(
         argv,

@@ -21,4 +21,28 @@ from repro.configs import (  # noqa: F401
     dlrm_rm2,
 )
 
-__all__ = ["ArchSpec", "ShapeCell", "get_arch", "register", "list_archs"]
+TINY_BERT = "bert-tiny"
+
+
+def bert_tower(arch_id: str):
+    """The BertConfig the drivers' ``--arch`` names: ``bert-tiny`` (the CPU
+    default, ``models.bert.tiny_bert``) or any registered bert-family arch
+    (e.g. ``dpr-bert-base``, the paper's bert-base-uncased towers)."""
+    from repro.models.bert import tiny_bert
+
+    if arch_id == TINY_BERT:
+        return tiny_bert()
+    spec = get_arch(arch_id)
+    if spec.family != "bert":
+        raise ValueError(f"--arch {arch_id!r} is a {spec.family} arch, not a bert tower")
+    return spec.model_cfg
+
+
+def bert_archs():
+    return [TINY_BERT] + [a for a in list_archs() if get_arch(a).family == "bert"]
+
+
+__all__ = [
+    "ArchSpec", "ShapeCell", "get_arch", "register", "list_archs",
+    "TINY_BERT", "bert_tower", "bert_archs",
+]

@@ -15,7 +15,7 @@ from repro.core.loss import (
     LossBackend, DenseLossBackend, FusedLossBackend, LOSS_BACKENDS,
     resolve_loss_backend,
 )
-from repro.core.dist import DistCtx, get_shard_map
+from repro.core.dist import DistCtx
 from repro.core.precision import (
     PRECISION_PRESETS,
     PrecisionPolicy,
@@ -65,7 +65,7 @@ __all__ = [
     "sharded_bank_extra_columns", "sharded_bank_extra_rows",
     "LossBackend", "DenseLossBackend", "FusedLossBackend", "LOSS_BACKENDS",
     "resolve_loss_backend",
-    "DistCtx", "get_shard_map",
+    "DistCtx",
     "PRECISION_PRESETS", "PrecisionPolicy", "apply_compute_dtype",
     "bank_bytes_per_device", "resolve_precision",
     "ContrastiveConfig", "ContrastiveState", "DualEncoder", "RetrievalBatch",

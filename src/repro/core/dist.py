@@ -23,22 +23,6 @@ import jax.numpy as jnp
 AxisNames = Union[str, Tuple[str, ...]]
 
 
-def get_shard_map():
-    """(shard_map, version_kwargs) across jax releases: >= 0.5 exposes
-    ``jax.shard_map`` with ``check_vma``; older releases keep it in
-    ``jax.experimental.shard_map`` with ``check_rep``. The kwargs disable
-    replication checking (the update's psum'ed outputs are replicated by
-    construction, which the static checker cannot always prove)."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    if "check_vma" in inspect.signature(sm).parameters:
-        return sm, {"check_vma": False}
-    return sm, {"check_rep": False}
-
-
 class DistCtx:
     """axis=None -> single-device semantics (gather = identity, psum = identity)."""
 

@@ -199,11 +199,6 @@ class FusedLossBackend:
     def row_stats(self, q_rows, p_all, labels, col_mask, *, temperature):
         from repro.kernels.fused_infonce.ops import fused_infonce_stats
 
-        interpret = (
-            jax.default_backend() != "tpu"
-            if self.interpret is None
-            else self.interpret
-        )
         # q/p may be bf16 (compute dtype); the kernel casts block loads to a
         # common dtype and keeps all statistics + VJP accumulation in fp32
         lse, pos, amax = fused_infonce_stats(
@@ -214,7 +209,7 @@ class FusedLossBackend:
             1.0 / float(temperature),
             self.block_m,
             self.block_n,
-            interpret,
+            self.interpret,
         )
         # amax is metrics-only (its VJP cotangent is discarded by the kernel).
         # Tie semantics differ from dense on exact fp32 logit ties: here a
@@ -226,11 +221,6 @@ class FusedLossBackend:
     def chunk_stats(self, q_rows, p_chunk, labels, col_mask, *, temperature):
         from repro.kernels.fused_infonce.ops import fused_infonce_stats
 
-        interpret = (
-            jax.default_backend() != "tpu"
-            if self.interpret is None
-            else self.interpret
-        )
         # the kernel handles out-of-range labels natively: the one-hot select
         # never fires, so pos stays 0 with zero gradient — exactly the
         # non-owning-chunk contract
@@ -242,7 +232,7 @@ class FusedLossBackend:
             1.0 / float(temperature),
             self.block_m,
             self.block_n,
-            interpret,
+            self.interpret,
         )
 
 

@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.embedding_bag.embedding_bag import embedding_bag_fwd
 
 
@@ -26,13 +27,16 @@ def _zero_empty(out, bag_ids, n_bags):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def embedding_bag(table, indices, bag_ids, n_bags, interpret=True):
-    out = embedding_bag_fwd(table, indices, bag_ids, n_bags, interpret=interpret)
+def embedding_bag(table, indices, bag_ids, n_bags, interpret=None):
+    """``interpret=None``: compiled on TPU, interpreter elsewhere."""
+    out = embedding_bag_fwd(table, indices, bag_ids, n_bags,
+                            interpret=resolve_interpret(interpret))
     return _zero_empty(out, bag_ids, n_bags)
 
 
 def _fwd(table, indices, bag_ids, n_bags, interpret):
-    out = embedding_bag_fwd(table, indices, bag_ids, n_bags, interpret=interpret)
+    out = embedding_bag_fwd(table, indices, bag_ids, n_bags,
+                            interpret=resolve_interpret(interpret))
     return _zero_empty(out, bag_ids, n_bags), (table.shape, indices, bag_ids)
 
 

@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.flash_attention import flash_attention_fwd
 from repro.models.attention import chunked_attention
 
@@ -59,8 +60,9 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     if kv_mask is None:
         kv_mask = jnp.ones((q.shape[0], k.shape[1]), dtype=bool)
-    return _flash(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret)
+    return _flash(q, k, v, kv_mask, causal, scale, block_q, block_k,
+                  resolve_interpret(interpret))

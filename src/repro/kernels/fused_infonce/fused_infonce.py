@@ -52,8 +52,7 @@ NEG_INF = -1e30
 
 
 def _fwd_kernel(labels_ref, valid_ref, q_ref, p_ref, lse_ref, pos_ref, amax_ref,
-                m_scr, l_scr, *, inv_tau, bm, bn, n_blocks):
-    i = pl.program_id(0)
+                m_scr, l_scr, *, inv_tau, bn, n_blocks):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -69,25 +68,26 @@ def _fwd_kernel(labels_ref, valid_ref, q_ref, p_ref, lse_ref, pos_ref, amax_ref,
         preferred_element_type=jnp.float32,
     ) * inv_tau  # (bm, bn)
     # invalid columns never enter the softmax (bank warm-up slots, padding)
-    vld = valid_ref[pl.ds(j * bn, bn)] != 0
-    s = jnp.where(vld[None, :], s, NEG_INF)
+    vld = valid_ref[...] != 0                                   # (1, bn)
+    s = jnp.where(vld, s, NEG_INF)
 
+    # per-row statistics are (bm, 1) columns throughout: 2-D keeps them in
+    # the layout Mosaic and XLA agree on for any row count
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.exp(s - m_new[:, None]).sum(axis=-1)
+    l_scr[...] = l_scr[...] * corr + jnp.exp(s - m_new).sum(
+        axis=-1, keepdims=True
+    )
     m_scr[...] = m_new
 
     # positive logit: label inside this column block?
-    # (scalar-prefetch operands arrive unblocked: slice this row block)
-    lbl = labels_ref[pl.ds(i * bm, bm)]
-    col0 = j * bn
-    local = lbl - col0
-    in_blk = (local >= 0) & (local < bn)
+    local = labels_ref[...] - j * bn                            # (bm, 1)
     onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) == local[:, None]
+        jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) == local
     ).astype(jnp.float32)
-    pos_j = (s * onehot).sum(axis=-1)
+    pos_j = (s * onehot).sum(axis=-1, keepdims=True)
+    in_blk = onehot.sum(axis=-1, keepdims=True) > 0
     pos_ref[...] = jnp.where(in_blk, pos_j, pos_ref[...])
 
     @pl.when(j == n_blocks - 1)
@@ -119,7 +119,12 @@ def _prep_operands(q, p, labels, col_valid, m_pad, n_pad):
     padded columns are marked invalid (masked to NEG_INF in-kernel). q/p are
     reconciled to a common compute dtype (dtype-aware block loads: bf16
     stays bf16, mixed bf16/fp32 inputs promote to fp32) — the in-kernel
-    matmuls accumulate in fp32 regardless."""
+    matmuls accumulate in fp32 regardless.
+
+    ``labels`` and the validity mask are ordinary VMEM-blocked operands, laid
+    out 2-D so each grid step loads only its own (bm, 1) label column and
+    (1, bn) validity row: a bank-scale mask never has to fit scalar memory,
+    and the kernel body reads vectors, never scalars."""
     n = p.shape[0]
     ct = jnp.result_type(q.dtype, p.dtype)
     valid = (
@@ -130,8 +135,8 @@ def _prep_operands(q, p, labels, col_valid, m_pad, n_pad):
     return (
         _pad_axis0(q.astype(ct), m_pad),
         _pad_axis0(p.astype(ct), n_pad),
-        _pad_axis0(labels.astype(jnp.int32), m_pad),
-        _pad_axis0(valid, n_pad),
+        _pad_axis0(labels.astype(jnp.int32), m_pad)[:, None],
+        _pad_axis0(valid, n_pad)[None, :],
     )
 
 
@@ -158,54 +163,46 @@ def fused_infonce_fwd(
     grid = (m_pad // bm, n_pad // bn)
 
     kernel = functools.partial(
-        _fwd_kernel, inv_tau=inv_tau, bm=bm, bn=bn, n_blocks=grid[1]
+        _fwd_kernel, inv_tau=inv_tau, bn=bn, n_blocks=grid[1]
     )
     lse, pos, amax = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+        grid_spec=pl.GridSpec(
             grid=grid,
             in_specs=[
-                pl.BlockSpec((bm, d), lambda i, j, labels, valid: (i, 0)),
-                pl.BlockSpec((bn, d), lambda i, j, labels, valid: (j, 0)),
+                pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+                pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+                pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
             ],
-            out_specs=[
-                pl.BlockSpec((bm,), lambda i, j, labels, valid: (i,)),
-                pl.BlockSpec((bm,), lambda i, j, labels, valid: (i,)),
-                pl.BlockSpec((bm,), lambda i, j, labels, valid: (i,)),
-            ],
+            out_specs=[pl.BlockSpec((bm, 1), lambda i, j: (i, 0))] * 3,
             scratch_shapes=[
-                pltpu.VMEM((bm,), jnp.float32),
-                pltpu.VMEM((bm,), jnp.float32),
+                pltpu.VMEM((bm, 1), jnp.float32),
+                pltpu.VMEM((bm, 1), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((m_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((m_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((m_pad,), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((m_pad, 1), jnp.float32)] * 3,
         interpret=interpret,
     )(labels, valid, q, p)
-    return lse[:m], pos[:m], amax[:m]
+    return lse[:m, 0], pos[:m, 0], amax[:m, 0]
 
 
-def _coeff(s, vld, lse_rows, labels, col0, bn, g_lse, g_pos):
+def _coeff(s, vld, lse_rows, labels, col0, g_lse, g_pos):
     """Per-tile cotangent of the logits: prob * g_lse + onehot * g_pos.
     Zero for invalid columns — the dense path's ``where`` mask has exactly
-    zero gradient w.r.t. a masked logit."""
-    prob = jnp.exp(s - lse_rows[:, None])
-    local = labels - col0
+    zero gradient w.r.t. a masked logit. ``labels`` is the (bm, 1) label
+    column, ``vld`` the (1, bn) validity row; row statistics are (bm, 1)."""
+    prob = jnp.exp(s - lse_rows)
     onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) == local[:, None]
+        jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) == labels - col0
     ).astype(jnp.float32)
-    coeff = prob * g_lse[:, None] + onehot * g_pos[:, None]
-    return jnp.where(vld[None, :], coeff, 0.0)
+    coeff = prob * g_lse + onehot * g_pos
+    return jnp.where(vld, coeff, 0.0)
 
 
 def _dq_kernel(labels_ref, valid_ref, q_ref, p_ref, lse_ref, glse_ref, gpos_ref,
-               dq_ref, *, inv_tau, bm, bn):
+               dq_ref, *, inv_tau, bn):
     """dQ = sum over column blocks of coeff @ P * inv_tau."""
-    i = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -216,10 +213,10 @@ def _dq_kernel(labels_ref, valid_ref, q_ref, p_ref, lse_ref, glse_ref, gpos_ref,
         q_ref[...], p_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * inv_tau
-    vld = valid_ref[pl.ds(j * bn, bn)] != 0
-    s = jnp.where(vld[None, :], s, NEG_INF)
-    coeff = _coeff(s, vld, lse_ref[...], labels_ref[pl.ds(i * bm, bm)], j * bn,
-                   bn, glse_ref[...], gpos_ref[...]) * inv_tau
+    vld = valid_ref[...] != 0
+    s = jnp.where(vld, s, NEG_INF)
+    coeff = _coeff(s, vld, lse_ref[...], labels_ref[...], j * bn,
+                   glse_ref[...], gpos_ref[...]) * inv_tau
     dq_ref[...] += jax.lax.dot_general(
         coeff.astype(p_ref.dtype), p_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -227,7 +224,7 @@ def _dq_kernel(labels_ref, valid_ref, q_ref, p_ref, lse_ref, glse_ref, gpos_ref,
 
 
 def _dp_kernel(labels_ref, valid_ref, q_ref, p_ref, lse_ref, glse_ref, gpos_ref,
-               dp_ref, *, inv_tau, bm, bn):
+               dp_ref, *, inv_tau, bn):
     """dP = sum over row blocks of coeff^T @ Q * inv_tau.
     Grid: (N/bn, M/bm) — column blocks outer, row blocks inner (accumulated)."""
     i = pl.program_id(1)
@@ -241,10 +238,10 @@ def _dp_kernel(labels_ref, valid_ref, q_ref, p_ref, lse_ref, glse_ref, gpos_ref,
         q_ref[...], p_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * inv_tau  # (bm, bn)
-    vld = valid_ref[pl.ds(j * bn, bn)] != 0
-    s = jnp.where(vld[None, :], s, NEG_INF)
-    coeff = _coeff(s, vld, lse_ref[...], labels_ref[pl.ds(i * bm, bm)], j * bn,
-                   bn, glse_ref[...], gpos_ref[...]) * inv_tau
+    vld = valid_ref[...] != 0
+    s = jnp.where(vld, s, NEG_INF)
+    coeff = _coeff(s, vld, lse_ref[...], labels_ref[...], j * bn,
+                   glse_ref[...], gpos_ref[...]) * inv_tau
     dp_ref[...] += jax.lax.dot_general(
         coeff.astype(q_ref.dtype), q_ref[...], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -268,24 +265,23 @@ def fused_infonce_bwd(
     # padded rows carry zero cotangents and lse=0, so their uniform
     # exp(0 - 0) probabilities never reach dQ/dP. Statistics and cotangents
     # are fp32 in-kernel whatever dtype q/p arrive in (accum_dtype contract).
-    lse = _pad_axis0(lse.astype(jnp.float32), m_pad)
-    g_lse = _pad_axis0(g_lse.astype(jnp.float32), m_pad)
-    g_pos = _pad_axis0(g_pos.astype(jnp.float32), m_pad)
+    lse, g_lse, g_pos = (
+        _pad_axis0(x.astype(jnp.float32), m_pad)[:, None]
+        for x in (lse, g_lse, g_pos)
+    )
     grid_q = (m_pad // bm, n_pad // bn)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, inv_tau=inv_tau, bm=bm, bn=bn),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+        functools.partial(_dq_kernel, inv_tau=inv_tau, bn=bn),
+        grid_spec=pl.GridSpec(
             grid=grid_q,
             in_specs=[
-                pl.BlockSpec((bm, d), lambda i, j, labels, valid: (i, 0)),
-                pl.BlockSpec((bn, d), lambda i, j, labels, valid: (j, 0)),
-                pl.BlockSpec((bm,), lambda i, j, labels, valid: (i,)),
-                pl.BlockSpec((bm,), lambda i, j, labels, valid: (i,)),
-                pl.BlockSpec((bm,), lambda i, j, labels, valid: (i,)),
-            ],
-            out_specs=pl.BlockSpec((bm, d), lambda i, j, labels, valid: (i, 0)),
+                pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+                pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+                pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
+            ] + [pl.BlockSpec((bm, 1), lambda i, j: (i, 0))] * 3,
+            out_specs=pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, d), jnp.float32),
         interpret=interpret,
@@ -293,18 +289,16 @@ def fused_infonce_bwd(
 
     grid_p = (n_pad // bn, m_pad // bm)
     dp = pl.pallas_call(
-        functools.partial(_dp_kernel, inv_tau=inv_tau, bm=bm, bn=bn),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+        functools.partial(_dp_kernel, inv_tau=inv_tau, bn=bn),
+        grid_spec=pl.GridSpec(
             grid=grid_p,
             in_specs=[
-                pl.BlockSpec((bm, d), lambda j, i, labels, valid: (i, 0)),
-                pl.BlockSpec((bn, d), lambda j, i, labels, valid: (j, 0)),
-                pl.BlockSpec((bm,), lambda j, i, labels, valid: (i,)),
-                pl.BlockSpec((bm,), lambda j, i, labels, valid: (i,)),
-                pl.BlockSpec((bm,), lambda j, i, labels, valid: (i,)),
-            ],
-            out_specs=pl.BlockSpec((bn, d), lambda j, i, labels, valid: (j, 0)),
+                pl.BlockSpec((bm, 1), lambda j, i: (i, 0)),
+                pl.BlockSpec((1, bn), lambda j, i: (0, j)),
+                pl.BlockSpec((bm, d), lambda j, i: (i, 0)),
+                pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
+            ] + [pl.BlockSpec((bm, 1), lambda j, i: (i, 0))] * 3,
+            out_specs=pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
         interpret=interpret,

@@ -31,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.fused_infonce.fused_infonce import (
     fused_infonce_bwd,
     fused_infonce_fwd,
@@ -39,12 +40,14 @@ from repro.kernels.fused_infonce.fused_infonce import (
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def fused_infonce_stats(q, p, labels, col_valid, inv_tau=1.0, block_m=128,
-                        block_n=128, interpret=True):
+                        block_n=128, interpret=None):
     """(lse, pos, amax) per row. Differentiable w.r.t. q and p; ``col_valid``
-    ((N,) bool or None) masks columns out of the softmax and the gradients."""
+    ((N,) bool or None) masks columns out of the softmax and the gradients.
+    ``interpret=None``: compiled on TPU, interpreter elsewhere."""
     return fused_infonce_fwd(
         q, p, labels, col_valid=col_valid, inv_tau=inv_tau,
-        block_m=block_m, block_n=block_n, interpret=interpret,
+        block_m=block_m, block_n=block_n,
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -60,7 +63,8 @@ def _stats_bwd(inv_tau, block_m, block_n, interpret, res, cotangents):
     g_lse, g_pos, _ = cotangents  # amax is metrics-only: cotangent discarded
     dq, dp = fused_infonce_bwd(
         q, p, labels, lse, g_lse, g_pos, col_valid=col_valid,
-        inv_tau=inv_tau, block_m=block_m, block_n=block_n, interpret=interpret,
+        inv_tau=inv_tau, block_m=block_m, block_n=block_n,
+        interpret=resolve_interpret(interpret),
     )
     return dq, dp, None, None
 
@@ -98,7 +102,7 @@ def merge_row_stats(lse_chunks, pos_chunks, owns_chunks, amax_chunks):
 
 
 def fused_infonce_rows(q, p, labels, inv_tau=1.0, block_m=128, block_n=128,
-                       interpret=True):
+                       interpret=None):
     """(lse, pos) per row, all columns valid. Differentiable w.r.t. q and p."""
     lse, pos, _ = fused_infonce_stats(
         q, p, labels, None, inv_tau, block_m, block_n, interpret
@@ -115,10 +119,9 @@ def fused_infonce_loss(
     temperature: float = 1.0,
     block_m: int = 128,
     block_n: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
-    """Mean InfoNCE over rows. ``interpret=True`` runs the kernel body on CPU
-    (this container); on TPU pass interpret=False."""
+    """Mean InfoNCE over rows (``interpret`` as in ``fused_infonce_stats``)."""
     if labels is None:
         labels = jnp.arange(q.shape[0], dtype=jnp.int32)
     lse, pos, _ = fused_infonce_stats(

@@ -7,13 +7,16 @@ HBM: the kernel streams (block_q x block_n) tiles through VMEM and folds
 each tile into a per-row running top-k scratch (scores + global column ids),
 the search-side analogue of fused_infonce's online-softmax accumulator.
 
-Merge semantics per tile: concatenate the (bq, k) running best with the
-(bq, bn) fresh tile scores and re-take top_k. The running block sits first in
-the concatenation and earlier column blocks were folded earlier, so ties
-break toward the lowest column id — exactly ``lax.top_k`` over the full row
-(ref.py). Invalid columns (corpus padding, masked shards) are forced to
-NEG_INF with id -1, so k > n_valid rows come back with -1-id tail slots
-instead of garbage.
+Merge semantics per tile: k rounds of selection over the (bq, k) running
+best and the (bq, bn) fresh tile — take the row max, then the lowest
+candidate id holding it, emit that (score, id) and mask it out. Every id in
+the running block comes from an earlier column block, so ties break toward
+the lowest column id — exactly ``lax.top_k`` over the full row (ref.py).
+The selection is plain max/min/where vector work, which Mosaic lowers
+(``lax.top_k`` has no Pallas TPU lowering). Invalid columns (corpus padding,
+masked shards) are NEG_INF with id -1, so k > n_valid rows come back with
+-1-id tail slots instead of garbage. The running block is kept lane-dense:
+k is padded up to a multiple of 128 internally and sliced on return.
 
 Grid layout mirrors fused_infonce_fwd: (Q/bq, N/bn), N innermost so the
 top-k scratch carries across column blocks; outputs are written on the last
@@ -44,6 +47,10 @@ from repro.kernels.fused_infonce.fused_infonce import (
 )
 
 
+_TAKEN = -3e38  # below NEG_INF: a candidate already emitted this round
+_NO_ID = 2 ** 31 - 1
+
+
 def _topk_kernel(valid_ref, q_ref, p_ref, s_out, i_out, s_scr, i_scr,
                  *, inv_tau, k, bn, n_blocks):
     j = pl.program_id(1)
@@ -57,16 +64,40 @@ def _topk_kernel(valid_ref, q_ref, p_ref, s_out, i_out, s_scr, i_scr,
         q_ref[...], p_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * inv_tau                                              # (bq, bn)
-    vld = valid_ref[pl.ds(j * bn, bn)] != 0
-    s = jnp.where(vld[None, :], s, NEG_INF)
+    vld = valid_ref[...] != 0                                # (1, bn)
+    s = jnp.where(vld, s, NEG_INF)
     ids = j * bn + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    ids = jnp.where(vld[None, :], ids, -1)
+    ids = jnp.where(vld, ids, -1)
 
-    cat_s = jnp.concatenate([s_scr[...], s], axis=1)         # (bq, k + bn)
-    cat_i = jnp.concatenate([i_scr[...], ids], axis=1)
-    top_s, pos = jax.lax.top_k(cat_s, k)
+    run_s = s_scr[...]                                       # (bq, kp)
+    run_i = i_scr[...]
+    slot = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+
+    def select(r, carry):
+        run_s, s, out_s, out_i = carry
+        best = jnp.maximum(
+            run_s.max(axis=1, keepdims=True), s.max(axis=1, keepdims=True)
+        )                                                    # (bq, 1)
+        pick = jnp.minimum(
+            jnp.where(run_s == best, run_i, _NO_ID).min(axis=1, keepdims=True),
+            jnp.where(s == best, ids, _NO_ID).min(axis=1, keepdims=True),
+        )
+        # an exhausted row (only NEG_INF / taken left) yields an empty slot;
+        # empty slots and invalid columns share id -1, so masking by id
+        # retires every one of them at once
+        live = best > NEG_INF / 2
+        out_s = jnp.where(slot == r, jnp.where(live, best, NEG_INF), out_s)
+        out_i = jnp.where(slot == r, jnp.where(live, pick, -1), out_i)
+        run_s = jnp.where(run_i == pick, _TAKEN, run_s)
+        s = jnp.where(ids == pick, _TAKEN, s)
+        return run_s, s, out_s, out_i
+
+    _, _, top_s, top_i = jax.lax.fori_loop(
+        0, k, select,
+        (run_s, s, jnp.full_like(run_s, NEG_INF), jnp.full_like(run_i, -1)),
+    )
     s_scr[...] = top_s
-    i_scr[...] = jnp.take_along_axis(cat_i, pos, axis=1)
+    i_scr[...] = top_i
 
     @pl.when(j == n_blocks - 1)
     def _final():
@@ -101,34 +132,35 @@ def fused_topk(
     )
     q = _pad_axis0(q.astype(ct), m_pad)
     p = _pad_axis0(p.astype(ct), n_pad)
-    valid = _pad_axis0(valid, n_pad)
+    valid = _pad_axis0(valid, n_pad)[None, :]
     grid = (m_pad // bq, n_pad // bn)
+    kp = -(-k // 128) * 128  # lane-dense running block
 
     kernel = functools.partial(
         _topk_kernel, inv_tau=inv_tau, k=k, bn=bn, n_blocks=grid[1]
     )
     scores, ids = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+        grid_spec=pl.GridSpec(
             grid=grid,
             in_specs=[
-                pl.BlockSpec((bq, d), lambda i, j, valid: (i, 0)),
-                pl.BlockSpec((bn, d), lambda i, j, valid: (j, 0)),
+                pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+                pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((bq, k), lambda i, j, valid: (i, 0)),
-                pl.BlockSpec((bq, k), lambda i, j, valid: (i, 0)),
+                pl.BlockSpec((bq, kp), lambda i, j: (i, 0)),
+                pl.BlockSpec((bq, kp), lambda i, j: (i, 0)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bq, k), jnp.float32),
-                pltpu.VMEM((bq, k), jnp.int32),
+                pltpu.VMEM((bq, kp), jnp.float32),
+                pltpu.VMEM((bq, kp), jnp.int32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((m_pad, k), jnp.float32),
-            jax.ShapeDtypeStruct((m_pad, k), jnp.int32),
+            jax.ShapeDtypeStruct((m_pad, kp), jnp.float32),
+            jax.ShapeDtypeStruct((m_pad, kp), jnp.int32),
         ],
         interpret=interpret,
     )(valid, q, p)
-    return scores[:m], ids[:m]
+    return scores[:m, :k], ids[:m, :k]

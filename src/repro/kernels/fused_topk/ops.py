@@ -4,16 +4,16 @@
 ``fused_infonce_stats``: the (Q, N) score matrix streams tile-by-tile
 through VMEM with a per-row running top-k, never materializing in HBM.
 Inference-only (no VJP). ``interpret=None`` auto-selects: compiled on TPU,
-interpreter elsewhere (CPU-testable), matching FusedLossBackend.
+interpreter elsewhere (CPU-testable) — ``repro.kernels.resolve_interpret``.
 """
 
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.fused_topk.fused_topk import fused_topk
 
 
@@ -29,9 +29,8 @@ def fused_topk_scores(
     interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(scores (Q, k) fp32, ids (Q, k) int32; -1 ids mark empty slots)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return fused_topk(
         q, index, k, col_valid=col_valid, inv_tau=inv_tau,
-        block_q=block_q, block_n=block_n, interpret=interpret,
+        block_q=block_q, block_n=block_n,
+        interpret=resolve_interpret(interpret),
     )

@@ -1,9 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# The two lines above MUST run before any other import (including repro.*):
+# The lines above MUST run before any other import (including repro.*):
 # jax locks the device count at first initialization, and the dry-run needs
-# 512 placeholder host devices to build the production meshes.
+# 512 placeholder host devices to build the production meshes, on the CPU
+# even where a chip is attached.
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the single-pod (16,16) and multi-pod (2,16,16) production meshes, and record

@@ -4,10 +4,15 @@ a load test with single-query requests. CPU-runnable end to end.
 
   PYTHONPATH=src python -m repro.launch.serve --n-passages 1024 --n-queries 64
 
-Serve a model trained by launch/train.py (same tiny-bert tower config):
+Serve a model trained by launch/train.py (pass both the same ``--arch``):
 
   PYTHONPATH=src python -m repro.launch.train --steps 100 --checkpoint-dir /tmp/ckpt
   PYTHONPATH=src python -m repro.launch.serve --ckpt /tmp/ckpt
+
+The paper's bert-base-uncased towers over a bf16 index on a TPU:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch dpr-bert-base \
+      --precision bf16_banks --n-passages 32768 --q-len 32 --p-len 256
 
 Sharded bf16 index over an 8-way DP mesh with the fused Pallas search
 kernel (on CPU force the host devices first):
@@ -25,9 +30,10 @@ import time
 import jax
 import numpy as np
 
+from repro.configs import TINY_BERT, bert_archs, bert_tower
 from repro.core.precision import PRECISION_PRESETS
 from repro.data.retrieval import SyntheticRetrievalCorpus
-from repro.launch.train import tiny_bert
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.towers import make_bert_dual_encoder
 from repro.retrieval import (
     Retriever,
@@ -39,7 +45,13 @@ from repro.retrieval import (
 
 
 def main(argv=None):
+    """Returns (retriever, stats); next to the load-test numbers, stats
+    carries the served queries and their (ids, scores), in submission
+    order."""
     ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default=TINY_BERT, choices=bert_archs(),
+                    help="tower config from the registry (repro/configs); "
+                         "must match the checkpoint's")
     ap.add_argument("--ckpt", default=None,
                     help="runtime/trainer.py checkpoint dir: serve the "
                          "trained params instead of a fresh init")
@@ -59,10 +71,13 @@ def main(argv=None):
     ap.add_argument("--n-queries", type=int, default=64)
     ap.add_argument("--top-k", type=int, default=20)
     ap.add_argument("--max-batch", type=int, default=16)
+    ap.add_argument("--q-len", type=int, default=16)
+    ap.add_argument("--p-len", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = tiny_bert()
+    setup_compile_cache()
+    cfg = bert_tower(args.arch)
     enc = make_bert_dual_encoder(cfg, precision=args.precision)
     if args.ckpt:
         params, step = load_trained_params(args.ckpt)
@@ -70,7 +85,8 @@ def main(argv=None):
     else:
         params = enc.init(jax.random.PRNGKey(args.seed))
     corpus = SyntheticRetrievalCorpus(
-        n_passages=args.n_passages, q_len=16, p_len=32, seed=args.seed
+        n_passages=args.n_passages, vocab_size=cfg.vocab_size,
+        q_len=args.q_len, p_len=args.p_len, seed=args.seed,
     )
 
     rcfg = RetrieverConfig(
@@ -91,6 +107,12 @@ def main(argv=None):
         f"{store.shards} shard(s)) built in {time.time()-t0:.2f}s"
     )
 
+    # compile the one padded batch shape the server runs before the clock
+    # starts, so the load test times serving, not compilation
+    t0 = time.time()
+    retriever.search(corpus.queries[: args.max_batch])
+    print(f"search warm-up (compile + first batch): {time.time()-t0:.2f}s")
+
     server = make_server(
         retriever, max_batch=args.max_batch
     ).start()
@@ -99,11 +121,12 @@ def main(argv=None):
         futures = [
             server.submit(corpus.queries[i]) for i in range(args.n_queries)
         ]
-        hits = 0
-        for i, fut in enumerate(futures):
-            ids, scores = fut.get(timeout=60)
-            hits += int(i in ids)
+        results = [fut.get(timeout=60) for fut in futures]
         dt = time.time() - t0
+        for res in results:
+            if isinstance(res, Exception):
+                raise res
+        hits = sum(int(i in ids) for i, (ids, _) in enumerate(results))
         sizes = server.batch_sizes
         stats = {
             "qps": args.n_queries / dt,
@@ -111,6 +134,9 @@ def main(argv=None):
             "batch_mean": float(np.mean(sizes)),
             "batch_max": int(max(sizes)),
             "index_bytes_per_device": store.bytes_per_device(),
+            "queries": corpus.queries[: args.n_queries],
+            "ids": np.stack([ids for ids, _ in results]),
+            "scores": np.stack([scores for _, scores in results]),
         }
         print(
             f"served {args.n_queries} queries in {dt:.2f}s "
@@ -118,7 +144,7 @@ def main(argv=None):
             f"{stats['recall']:.3f}, mean coalesced batch "
             f"{stats['batch_mean']:.1f} (max {stats['batch_max']})"
         )
-        return stats
+        return retriever, stats
     finally:
         server.stop()
 

@@ -31,7 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.common.treemath import tree_add, tree_scale, tree_zeros_like
 from repro.configs import get_arch, list_archs
 from repro.configs.base import ArchSpec, ShapeCell
-from repro.core.dist import get_shard_map
 from repro.core.methods import build_step_program, init_state
 from repro.core.precision import bank_bytes_per_device, resolve_precision
 from repro.core.types import ContrastiveConfig, RetrievalBatch
@@ -588,19 +587,18 @@ def _contrastive_program(arch: ArchSpec, cell: ShapeCell, mesh: Mesh) -> CellPro
     program = build_step_program(enc, tx, ccfg)
     update = program.update
     if xdev:
-        sm, sm_kw = get_shard_map()
         state_spec = contrastive_state_spec(dp, shard_banks)
         batch_spec = RetrievalBatch(
             query=P(dp, None),
             passage_pos=P(dp, None),
             passage_hard=P(dp, None, None),
         )
-        update = sm(
+        update = jax.shard_map(
             program.update,
             mesh=mesh,
             in_specs=(state_spec, batch_spec),
             out_specs=(state_spec, P()),
-            **sm_kw,
+            check_vma=False,
         )
 
     state_s = jax.eval_shape(

@@ -1,11 +1,19 @@
 """Training driver: the paper's dense-retriever training (any of the four
 methods) on synthetic or DPR-format data, wired through the fault-tolerant
-Trainer. CPU-runnable end to end at reduced scale; the same step functions
-lower for the production meshes via launch/dryrun.py.
+Trainer. CPU-runnable end to end at reduced scale (the default ``--arch
+bert-tiny`` tower); the same step functions lower for the production meshes
+via launch/dryrun.py.
 
   PYTHONPATH=src python -m repro.launch.train \
       --method contaccum --total-batch 128 --local-batch 8 --bank 512 \
       --steps 200 --checkpoint-dir /tmp/ckpt
+
+The paper's model and geometry (bert-base-uncased towers from the config
+registry, N_total=128, N_local=8, N_mem=2048, q_len 32, p_len 256) on a TPU:
+
+  PYTHONPATH=src python -m repro.launch.train --arch dpr-bert-base \
+      --method contaccum --total-batch 128 --local-batch 8 --bank 2048 \
+      --q-len 32 --p-len 256 --precision bf16_banks --loss-impl fused
 
 Data-parallel shard_map path (requires >= N devices, e.g.
 XLA_FLAGS=--xla_force_host_platform_device_count=8 on CPU): ``--dp N``
@@ -34,12 +42,14 @@ carries mined columns:
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dist import get_shard_map
+from repro.configs import TINY_BERT, bert_archs, bert_tower
 from repro.core.methods import (
     available_methods,
     build_step_program,
@@ -52,27 +62,41 @@ from repro.core.precision import PRECISION_PRESETS
 from repro.core.types import ContrastiveConfig, RetrievalBatch
 from repro.data.loader import ShardedLoader
 from repro.data.retrieval import SyntheticRetrievalCorpus
-from repro.models.bert import BertConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.towers import make_bert_dual_encoder
 from repro.optim.adamw import adamw, chain, clip_by_global_norm
 from repro.optim.schedules import linear_warmup_linear_decay
 from repro.runtime.trainer import Trainer, TrainerConfig
 
 
-def tiny_bert(vocab: int = 1000) -> BertConfig:
-    return BertConfig(
-        name="bert-tiny",
-        n_layers=2,
-        d_model=64,
-        n_heads=4,
-        d_ff=128,
-        vocab_size=vocab,
-        max_position=64,
-    )
+@dataclasses.dataclass
+class TrainStep:
+    """The jitted update (state donated) and what its state is built from."""
+
+    tower: Any
+    enc: Any
+    tx: Any
+    cfg: ContrastiveConfig
+    update: Any
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    """Everything ``main`` runs: the jitted update, its initial state, and
+    the Trainer that drives it (plus the miner, when mining)."""
+
+    update: Any
+    state: Any
+    trainer: Trainer
+    miner: Optional[Any] = None
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default=TINY_BERT, choices=bert_archs(),
+                    help="tower config from the registry (repro/configs): "
+                         "bert-tiny for CPU runs, dpr-bert-base for the "
+                         "paper's bert-base-uncased towers")
     # mesh-requiring compositions can't build in this single-program driver;
     # only offer methods it can actually run
     methods = [m for m in available_methods() if not method_needs_mesh(m)]
@@ -128,11 +152,23 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--lr", type=float, default=2e-4)
     ap.add_argument("--corpus-size", type=int, default=2048)
+    ap.add_argument("--q-len", type=int, default=16,
+                    help="query tokens (<= the tower's max_position)")
+    ap.add_argument("--p-len", type=int, default=32,
+                    help="passage tokens (<= the tower's max_position)")
     ap.add_argument("--checkpoint-dir", default=None)
     ap.add_argument("--checkpoint-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def _mines(args) -> bool:
+    source, _ = method_composition(args.method)
+    return args.negatives == "mined" or source == "mined"
+
+
+def build_step(args) -> TrainStep:
+    """Validate the flags and build the jitted update; allocates nothing."""
     dp = args.dp
     if args.shard_banks and not dp:
         raise SystemExit("--shard-banks needs --dp N (banks shard over the DP mesh)")
@@ -152,8 +188,7 @@ def main(argv=None):
         if args.shard_banks and args.bank % dp:
             raise SystemExit(f"--bank {args.bank} not divisible by --dp {dp}")
 
-    source, _ = method_composition(args.method)
-    mine = args.negatives == "mined" or source == "mined"
+    mine = _mines(args)
     # with a bank method the banks stay the source and mined columns ride
     # the batch (contaccum x mined); otherwise the source becomes 'mined'
     negatives = (
@@ -178,7 +213,13 @@ def main(argv=None):
         shard_banks=bool(args.shard_banks and dp and bank),
         loss_comm=args.loss_comm,
     )
-    enc = make_bert_dual_encoder(tiny_bert(), precision=args.precision)
+    tower = bert_tower(args.arch)
+    if max(args.q_len, args.p_len) > tower.max_position:
+        raise SystemExit(
+            f"--q-len/--p-len exceed {args.arch}'s max_position "
+            f"{tower.max_position}"
+        )
+    enc = make_bert_dual_encoder(tower, precision=args.precision)
     tx = chain(
         clip_by_global_norm(cfg.grad_clip_norm),
         adamw(linear_warmup_linear_decay(args.lr, args.steps // 10, args.steps)),
@@ -192,21 +233,28 @@ def main(argv=None):
         from repro.distribution.sharding import contrastive_state_spec
 
         mesh = Mesh(np.array(jax.devices()[:dp]), ("data",))
-        sm, sm_kw = get_shard_map()
         state_spec = contrastive_state_spec(("data",), cfg.shard_banks)
         batch_spec = RB(query=P("data"), passage_pos=P("data"), passage_hard=P("data"))
-        update = sm(
+        update = jax.shard_map(
             update,
             mesh=mesh,
             in_specs=(state_spec, batch_spec),
             out_specs=(state_spec, P()),
-            **sm_kw,
+            check_vma=False,
         )
     update = jax.jit(update, donate_argnums=(0,))
-    state = init_state(jax.random.PRNGKey(args.seed), enc, tx, cfg)
+    return TrainStep(tower=tower, enc=enc, tx=tx, cfg=cfg, update=update)
+
+
+def build(args) -> TrainRun:
+    """Assemble the update, its initial state, the data and the Trainer."""
+    ts = build_step(args)
+    mine = _mines(args)
+    state = init_state(jax.random.PRNGKey(args.seed), ts.enc, ts.tx, ts.cfg)
 
     corpus = SyntheticRetrievalCorpus(
-        n_passages=args.corpus_size, q_len=16, p_len=32, seed=args.seed
+        n_passages=args.corpus_size, vocab_size=ts.tower.vocab_size,
+        q_len=args.q_len, p_len=args.p_len, seed=args.seed,
     )
     loader = ShardedLoader(args.corpus_size, args.total_batch, seed=args.seed)
 
@@ -231,7 +279,7 @@ def main(argv=None):
         )
         # corpus alignment: query i's gold passage IS passage i
         miner = HardNegativeMiner(
-            enc, mcfg, queries=corpus.queries, passages=corpus.passages
+            ts.enc, mcfg, queries=corpus.queries, passages=corpus.passages
         )
         injector = MinedNegativeInjector(
             miner.buffer.read,
@@ -240,12 +288,15 @@ def main(argv=None):
             state=loader.state,
             on_step=miner.note_step,
         )
+        # not advisory: a failed refresh fails the run instead of training
+        # on silently stale negatives
         hooks.append(
             PeriodicHook(
                 every=mcfg.refresh_every,
                 fn=miner.refresh_hook,
                 prefix="mine/",
                 name="mine",
+                advisory=False,
             )
         )
 
@@ -268,13 +319,21 @@ def main(argv=None):
             checkpoint_dir=args.checkpoint_dir,
             checkpoint_every=args.checkpoint_every,
         ),
-        update,
+        ts.update,
         next_batch,
         loader_state=loader.state,
         hooks=hooks,
         aux_state=miner,
     )
-    state, report = trainer.run(state)
+    return TrainRun(update=ts.update, state=state, trainer=trainer, miner=miner)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    setup_compile_cache()
+    run = build(args)
+    state, report = run.trainer.run(run.state)
+    miner = run.miner
     if miner is not None:
         miner.close()
         print(

@@ -59,6 +59,19 @@ class BertConfig:
         return self.n_layers * per_layer + emb
 
 
+def tiny_bert(vocab: int = 1000) -> BertConfig:
+    """Two-layer, d=64 tower: the drivers' default, sized for CPU tests."""
+    return BertConfig(
+        name="bert-tiny",
+        n_layers=2,
+        d_model=64,
+        n_heads=4,
+        d_ff=128,
+        vocab_size=vocab,
+        max_position=64,
+    )
+
+
 def init_bert(rng, cfg: BertConfig):
     d, nl = cfg.d_model, cfg.n_layers
     ks = jax.random.split(rng, 10)

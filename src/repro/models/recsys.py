@@ -216,11 +216,7 @@ def make_psum_scatter_lookup(mesh, table_axes=("model", "data"),
         return jax.lax.psum_scatter(part, gs_axes, scatter_dimension=0,
                                     tiled=True)        # (b/dev, F, D)
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # jax < 0.5 keeps it under experimental
-        from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(table_axes, None), P(batch_axes, None)),

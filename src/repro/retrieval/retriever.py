@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dist import DistCtx, get_shard_map
+from repro.core.dist import DistCtx
 from repro.core.precision import PrecisionPolicy, resolve_precision
 from repro.core.types import DualEncoder
 from repro.kernels.fused_infonce.fused_infonce import NEG_INF
@@ -240,14 +240,13 @@ class Retriever:
             # batch; the index (the big operand) never moves
             return local(params, reps, row_valid, queries, ctx.shard_index(), ctx)
 
-        sm, sm_kw = get_shard_map()
         return jax.jit(
-            sm(
+            jax.shard_map(
                 sharded,
                 mesh=self.mesh,
                 in_specs=(P(), P(ax, None), P(ax), P()),
                 out_specs=(P(), P()),
-                **sm_kw,
+                check_vma=False,
             )
         )
 

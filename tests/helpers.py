@@ -13,15 +13,6 @@ import jax.numpy as jnp
 from repro.core.types import DualEncoder, RetrievalBatch
 
 
-def get_shard_map():
-    """(shard_map, kwargs) across jax versions: >= 0.5 has jax.shard_map with
-    ``check_vma``; older releases keep it in experimental with ``check_rep``.
-    Delegates to the production helper so tests and launch code can't drift."""
-    from repro.core.dist import get_shard_map as _impl
-
-    return _impl()
-
-
 def make_mlp_encoder(dim_in: int = 16, dim_hidden: int = 32, dim_rep: int = 8) -> DualEncoder:
     def tower_init(rng):
         k1, k2 = jax.random.split(rng)

@@ -22,8 +22,6 @@ SCRIPT = textwrap.dedent(
     from jax.sharding import Mesh, PartitionSpec as P
 
     sys.path.insert(0, "tests")
-    from helpers import get_shard_map
-    shard_map, _vma_kw = get_shard_map()
     from helpers import make_mlp_encoder, make_batch
     from repro.core import (
         ContrastiveConfig, RetrievalBatch, init_state, make_update_fn,
@@ -70,12 +68,12 @@ SCRIPT = textwrap.dedent(
                 passage_pos=P(("pod", "data")),
                 passage_hard=None,
             )
-            update = shard_map(
+            update = jax.shard_map(
                 update,
                 mesh=mesh,
                 in_specs=(P(), batch_spec),
                 out_specs=(P(), P()),
-                **_vma_kw,
+                check_vma=False,
             )
         update = jax.jit(update)
         losses = []
@@ -129,8 +127,7 @@ SHARDED_SCRIPT = textwrap.dedent(
     from jax.sharding import Mesh, PartitionSpec as P
 
     sys.path.insert(0, "tests")
-    from helpers import get_shard_map, make_mlp_encoder, make_batch
-    shard_map, _vma_kw = get_shard_map()
+    from helpers import make_mlp_encoder, make_batch
     from repro.core import (
         ContrastiveConfig, RetrievalBatch, init_state, make_update_fn,
     )
@@ -176,12 +173,12 @@ SHARDED_SCRIPT = textwrap.dedent(
             batch_spec = RetrievalBatch(
                 query=P(DP), passage_pos=P(DP), passage_hard=None
             )
-            update = shard_map(
+            update = jax.shard_map(
                 update,
                 mesh=mesh,
                 in_specs=(state_spec, batch_spec),
                 out_specs=(state_spec, P()),
-                **_vma_kw,
+                check_vma=False,
             )
         update = jax.jit(update)
         losses, fills = [], []

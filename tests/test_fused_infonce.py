@@ -32,10 +32,10 @@ from repro.core import (
     resolve_loss_backend,
 )
 from repro.kernels.fused_infonce.ops import fused_infonce_stats
-from repro.kernels.fused_infonce.ref import infonce_stats_ref
+from repro.kernels.fused_infonce.ref import NEG_INF, infonce_stats_ref
 from repro.optim import chain, clip_by_global_norm, sgd
 
-from helpers import get_shard_map, make_batch, make_mlp_encoder
+from helpers import make_batch, make_mlp_encoder
 
 ALL_COMPOSITIONS = [
     (neg, bp) for neg in sorted(SOURCES) for bp in sorted(STRATEGIES)
@@ -71,13 +71,12 @@ def _run_trajectory(neg, bp, loss_impl, batches):
     if neg == "gathered":
         from jax.sharding import Mesh, PartitionSpec as P
 
-        shard_map, sm_kw = get_shard_map()
         mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
         spec = RetrievalBatch(query=P("dp"), passage_pos=P("dp"),
                               passage_hard=P("dp"))
-        update = jax.jit(shard_map(
+        update = jax.jit(jax.shard_map(
             program.update, mesh=mesh, in_specs=(P(), spec),
-            out_specs=(P(), P()), **sm_kw,
+            out_specs=(P(), P()), check_vma=False,
         ))
     else:
         update = jax.jit(program.update)
@@ -211,6 +210,50 @@ def test_odd_shapes_are_padded_internally(m, n, d, bm, bn):
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=5e-6,
             err_msg=f"odd-shape VJP mismatch: {name}",
         )
+
+
+@pytest.mark.parametrize(
+    "m,n,bm,bn,tail,label_lo,label_hi",
+    [
+        (37, 300, 16, 128, 77, 0, 223),      # ragged M/N, masked bank tail
+        (64, 256, 32, 128, 128, 0, 128),     # a whole column block masked
+        (24, 200, 8, 64, 0, -200, 400),      # ring chunk: labels owned elsewhere
+    ],
+)
+def test_blocked_labels_and_validity(m, n, bm, bn, tail, label_lo, label_hi):
+    """labels arrive as a (bm, 1) column block and validity as a (1, bn) row
+    block per grid step: a masked tail never enters the softmax, and a label
+    outside [0, n) (a row whose positive lives in another ring chunk) gives
+    pos 0 with no gradient through it."""
+    ks = jax.random.split(jax.random.PRNGKey(m + n + tail), 4)
+    q = jax.random.normal(ks[0], (m, 16))
+    p = jax.random.normal(ks[1], (n, 16))
+    labels = jax.random.randint(ks[2], (m,), label_lo, label_hi)
+    valid = jnp.arange(n) < n - tail
+    w = jax.random.uniform(ks[3], (m,))
+
+    def ref(q_, p_):
+        lse, _, amax = infonce_stats_ref(q_, p_, jnp.zeros_like(labels), valid,
+                                         inv_tau=1.3)
+        logits = jnp.where(valid[None, :], 1.3 * q_ @ p_.T, NEG_INF)
+        pos = jnp.sum(jax.nn.one_hot(labels, n) * logits, axis=-1)
+        return lse, pos, amax
+
+    def kern(q_, p_):
+        return fused_infonce_stats(q_, p_, labels, valid, 1.3, bm, bn, True)
+
+    for got, want in zip(kern(q, p), ref(q, p)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+    gk = jax.grad(lambda a, b: jnp.sum((kern(a, b)[0] - kern(a, b)[1]) * w),
+                  argnums=(0, 1))(q, p)
+    gr = jax.grad(lambda a, b: jnp.sum((ref(a, b)[0] - ref(a, b)[1]) * w),
+                  argnums=(0, 1))(q, p)
+    for name, a, b in zip(("dq", "dp"), gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=5e-6, err_msg=name)
+    if tail:
+        np.testing.assert_array_equal(np.asarray(gk[1])[n - tail:], 0.0)
 
 
 @pytest.mark.slow

@@ -51,7 +51,8 @@ def test_fused_infonce_grads_match_oracle(m, n, d):
     p = jax.random.normal(ks[1], (n, d))
     labels = jax.random.randint(ks[2], (m,), 0, n)
     gq, gp = jax.grad(
-        lambda q_, p_: fused_infonce_loss(q_, p_, labels, temperature=0.7),
+        lambda q_, p_: fused_infonce_loss(q_, p_, labels, temperature=0.7,
+                                           interpret=True),
         argnums=(0, 1),
     )(q, p)
     gq_r, gp_r = infonce_grads_ref(q, p, labels, inv_tau=1.0 / 0.7)
@@ -63,7 +64,7 @@ def test_fused_infonce_loss_value_jit():
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     q = jax.random.normal(ks[0], (128, 32))
     p = jax.random.normal(ks[1], (128, 32))
-    loss = jax.jit(lambda a, b: fused_infonce_loss(a, b))(q, p)
+    loss = jax.jit(lambda a, b: fused_infonce_loss(a, b, interpret=True))(q, p)
     loss_r = infonce_loss_ref(q, p, jnp.arange(128, dtype=jnp.int32))
     np.testing.assert_allclose(float(loss), float(loss_r), rtol=1e-6)
 
@@ -106,7 +107,8 @@ def test_flash_attention_fwd_sweep(b, sq, skv, h, hk, d, causal):
     q = jax.random.normal(ks[0], (b, sq, h, d))
     k = jax.random.normal(ks[1], (b, skv, hk, d))
     v = jax.random.normal(ks[2], (b, skv, hk, d))
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     ref = flash_attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -119,7 +121,8 @@ def test_flash_attention_kv_mask_and_dtype(dtype):
     k = jax.random.normal(ks[1], (b, s, h, d), dtype)
     v = jax.random.normal(ks[2], (b, s, h, d), dtype)
     mask = jax.random.bernoulli(ks[3], 0.7, (b, s)).at[:, 0].set(True)
-    out = flash_attention(q, k, v, kv_mask=mask, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, kv_mask=mask, block_q=64, block_k=64,
+                          interpret=True)
     ref = flash_attention_ref(q, k, v, kv_mask=mask)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -135,7 +138,8 @@ def test_flash_attention_grads_match_plain():
     v = jax.random.normal(ks[2], (b, s, h, d))
 
     def f_kernel(q_, k_, v_):
-        return flash_attention(q_, k_, v_, causal=True, block_q=64, block_k=64).sum()
+        return flash_attention(q_, k_, v_, causal=True, block_q=64, block_k=64,
+                               interpret=True).sum()
 
     def f_ref(q_, k_, v_):
         return flash_attention_ref(q_, k_, v_, causal=True).sum()

@@ -94,6 +94,28 @@ def test_backend_k_exceeds_valid_columns(impl, kw):
                                np.asarray(ref_s)[:, :4], atol=1e-5)
 
 
+@pytest.mark.parametrize("k,bn", [
+    (5, 16),      # k inside one tile
+    (40, 16),     # k spans several tiles
+    (130, 64),    # k past one 128-lane running block and past n_valid
+    (160, 32),    # k > n: every slot past the valid columns is empty
+])
+def test_fused_topk_selection_ties_and_empty_slots(k, bn):
+    """The kernel's selection (k rounds of row max, lowest id holding it,
+    mask it out) on all-equal scores: ids come back 0, 1, 2, ... in order
+    whatever the tiling, and slots past the valid columns are NEG_INF, -1."""
+    n, n_valid = 150, 100
+    q, p = jnp.ones((4, 8)), jnp.ones((n, 8))
+    valid = jnp.arange(n) < n_valid          # a masked tail of whole tiles
+    s, i = fused_topk_scores(q, p, k, col_valid=valid, block_q=8,
+                             block_n=bn, interpret=True)
+    s_r, i_r = topk_scores_ref(q, p, k, col_valid=valid)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(i_r))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s_r))
+    want = np.where(np.arange(k) < n_valid, np.arange(k), -1)
+    np.testing.assert_array_equal(np.asarray(i), np.broadcast_to(want, (4, k)))
+
+
 def test_fused_bf16_index_well_separated_ids_exact():
     """bf16 queries/index (the bf16_banks serving path): ids stay exact when
     scores are separated beyond bf16 rounding; scores match the bf16
@@ -105,7 +127,8 @@ def test_fused_bf16_index_well_separated_ids_exact():
     p *= (1.0 + np.arange(64))[:, None]          # well-separated norms
     q = rng.normal(size=(5, d)).astype(np.float32)
     qb, pb = jnp.asarray(q, jnp.bfloat16), jnp.asarray(p, jnp.bfloat16)
-    s_f, i_f = fused_topk_scores(qb, pb, 8, block_q=8, block_n=16)
+    s_f, i_f = fused_topk_scores(qb, pb, 8, block_q=8, block_n=16,
+                                 interpret=True)
     s_r, i_r = topk_scores_ref(qb, pb, 8)
     np.testing.assert_array_equal(np.asarray(i_f), np.asarray(i_r))
     np.testing.assert_allclose(np.asarray(s_f), np.asarray(s_r),
@@ -434,7 +457,7 @@ def test_trained_checkpoint_serves_end_to_end(tmp_path):
     assert step == 3
     assert "query" in params and "passage" in params
 
-    stats = serve_mod.main([
+    _, stats = serve_mod.main([
         "--ckpt", ckpt, "--n-passages", "64", "--n-queries", "8",
         "--top-k", "8", "--max-batch", "4",
     ])
@@ -443,9 +466,9 @@ def test_trained_checkpoint_serves_end_to_end(tmp_path):
     assert stats["batch_mean"] >= 1.0
 
     # the loaded params really are the trained ones, not a fresh init
-    enc = train_mod.tiny_bert()
-    from repro.models.bert import init_bert
+    from repro.models.bert import init_bert, tiny_bert
 
+    enc = tiny_bert()
     fresh = init_bert(jax.random.PRNGKey(0), enc)
     assert not np.allclose(
         np.asarray(params["query"]["embed"]["word"]),

@@ -38,8 +38,7 @@ PARITY_SCRIPT = textwrap.dedent(
     from jax.sharding import Mesh, PartitionSpec as P
 
     sys.path.insert(0, "tests")
-    from helpers import get_shard_map, make_mlp_encoder, make_batch
-    shard_map, _vma_kw = get_shard_map()
+    from helpers import make_mlp_encoder, make_batch
     from repro.core import (
         ContrastiveConfig, RetrievalBatch, init_state, make_update_fn,
     )
@@ -65,12 +64,12 @@ PARITY_SCRIPT = textwrap.dedent(
         batch_spec = RetrievalBatch(
             query=P(DP), passage_pos=P(DP), passage_hard=None
         )
-        update = jax.jit(shard_map(
+        update = jax.jit(jax.shard_map(
             make_update_fn(enc, tx, cfg),
             mesh=mesh,
             in_specs=(state_spec, batch_spec),
             out_specs=(state_spec, P()),
-            **_vma_kw,
+            check_vma=False,
         ))
         losses, accs, negs = [], [], []
         for i in range(steps):
@@ -136,8 +135,6 @@ ROTATE_VJP_SCRIPT = textwrap.dedent(
     from jax.sharding import Mesh, PartitionSpec as P
 
     sys.path.insert(0, "tests")
-    from helpers import get_shard_map
-    shard_map, _vma_kw = get_shard_map()
     from repro.core.dist import DistCtx
 
     D = 8
@@ -152,9 +149,9 @@ ROTATE_VJP_SCRIPT = textwrap.dedent(
         y = ctx.ring_rotate(x, 1)          # device j receives x_{(j-1)%D}
         return ctx.psum(jnp.sum(y * c)), y
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         fwd, mesh=mesh, in_specs=(P(("pod", "data")), P(("pod", "data"))),
-        out_specs=(P(), P(("pod", "data"))), **_vma_kw,
+        out_specs=(P(), P(("pod", "data"))), check_vma=False,
     ))
     loss, y = f(x, c)
     # value: rotation by one in flattened (pod, data) ring order
@@ -166,14 +163,14 @@ ROTATE_VJP_SCRIPT = textwrap.dedent(
     assert abs(float(loss) - expect) < 1e-5, (float(loss), expect)
 
     # VJP: differentiate the device-LOCAL contribution sum_j c_j * y_j (no
-    # psum: its check_rep=False transpose re-reduces and scales by D). The
+    # psum: its check_vma=False transpose re-reduces and scales by D). The
     # cotangent c_j is created on the RECEIVING device j, and ppermute's
     # transpose (the inverse rotation) must deliver it back to the shard's
     # owner: d/dx_i = c_{(i+1)%D}.
-    g = jax.jit(shard_map(
+    g = jax.jit(jax.shard_map(
         jax.grad(lambda x, c: jnp.sum(ctx.ring_rotate(x, 1) * c)), mesh=mesh,
         in_specs=(P(("pod", "data")), P(("pod", "data"))),
-        out_specs=P(("pod", "data")), **_vma_kw,
+        out_specs=P(("pod", "data")), check_vma=False,
     ))(x, c)
     np.testing.assert_array_equal(
         np.asarray(g).ravel(), np.roll(np.arange(D) + 1.0, -1)
@@ -185,9 +182,9 @@ ROTATE_VJP_SCRIPT = textwrap.dedent(
             x = ctx.ring_rotate(x, 1)
         return x
 
-    rt = jax.jit(shard_map(
+    rt = jax.jit(jax.shard_map(
         full_circle, mesh=mesh, in_specs=(P(("pod", "data")),),
-        out_specs=P(("pod", "data")), **_vma_kw,
+        out_specs=P(("pod", "data")), check_vma=False,
     ))(x)
     np.testing.assert_array_equal(np.asarray(rt), np.asarray(x))
     print("ALL-OK")
@@ -210,8 +207,6 @@ TRANSIENT_SCRIPT = textwrap.dedent(
     from jax.sharding import Mesh, PartitionSpec as P
 
     sys.path.insert(0, "tests")
-    from helpers import get_shard_map
-    shard_map, _vma_kw = get_shard_map()
     from repro.core.dist import DistCtx
     from repro.core.loss import FusedLossBackend, contrastive_loss, \\
         sharded_bank_extra_columns
@@ -260,9 +255,9 @@ TRANSIENT_SCRIPT = textwrap.dedent(
             return f(q), q
 
         row = P(dp)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             eval_loss, mesh=mesh, in_specs=(row,) * 4,
-            out_specs=(P(), row), **_vma_kw,
+            out_specs=(P(), row), check_vma=False,
         ))
         mem = fn.lower(q, pp, pbuf, valid).compile().memory_analysis()
         return float(getattr(mem, "temp_size_in_bytes", 0))

@@ -314,3 +314,29 @@ def test_batching_server_propagates_errors():
         assert isinstance(res, RuntimeError)
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------- compile cache
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir_is_placed_from_outside(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and the helper sets
+    no other; unset, the cache is the fixed, git-ignored checkout path."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, setup_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert setup_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert setup_compile_cache() == str(CHECKOUT_CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+            root = Path(__file__).resolve().parents[1]
+            assert CHECKOUT_CACHE_DIR == root / ".jax_cache"
+            assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

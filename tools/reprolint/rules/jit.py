@@ -23,7 +23,7 @@ from tools.reprolint.astutil import call_name, dotted_name, function_param_names
 from tools.reprolint.engine import FileContext, RepoContext, Violation
 
 _JIT_SUFFIXES = ("jit",)                    # jax.jit, jit, pjit
-_SHARD_MAP_NAMES = {"shard_map", "sm"}      # get_shard_map() convention
+_SHARD_MAP_NAMES = {"shard_map"}            # jax.shard_map / bare shard_map
 
 #: calls that are host-only side effects under a trace
 _HOST_CALLS = {"print", "input", "breakpoint", "open"}
