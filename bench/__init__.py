@@ -1,0 +1,1 @@
+"""Benchmark of this repository: see BENCHMARK.json and PERF.md."""

@@ -1,0 +1,8 @@
+"""train.device_idle_share: share of the traced window in which no
+operation ran on the device (1 - union of op intervals / window)."""
+
+from bench.harness import readers
+
+
+def read(d):
+    return readers.idle_share_pct(d)
