@@ -412,13 +412,35 @@ class BackpropStrategy(Protocol):
         ...
 
 
+# Named scopes mark the update's layers (towers, loss, grad_accum,
+# bank_push, optimizer) in the compiled program's op_name metadata, and so
+# in a profiler trace of it: each device op carries at most one of them.
+# Differentiation keeps the name, as ``jvp(towers)`` and
+# ``transpose(jvp(towers))``. They are metadata only: the compiled code and
+# the compilation cache's key do not change.
 def _encode_chunk(encoder: DualEncoder, params, chunk: RetrievalBatch):
-    q = encoder.encode_query(params, chunk.query)
-    pp = encoder.encode_passage(params, chunk.passage_pos)
-    ph = None
-    if chunk.passage_hard is not None:
-        ph = encoder.encode_passage(params, flatten_hard(chunk.passage_hard))
-    return q, pp, ph
+    with jax.named_scope("towers"):
+        q = encoder.encode_query(params, chunk.query)
+        pp = encoder.encode_passage(params, chunk.passage_pos)
+        ph = None
+        if chunk.passage_hard is not None:
+            ph = encoder.encode_passage(params, flatten_hard(chunk.passage_hard))
+        return q, pp, ph
+
+
+def _loss(source: NegativeSource, q, pp, ph, carry, **kw):
+    with jax.named_scope("loss"):
+        return source.loss(q, pp, ph, carry, **kw)
+
+
+def _push(source: NegativeSource, carry, aux, step, **kw):
+    with jax.named_scope("bank_push"):
+        return source.push(carry, aux, step, **kw)
+
+
+def _accumulate(grads_acc, g):
+    with jax.named_scope("grad_accum"):
+        return tree_add(grads_acc, g)
 
 
 def _chunk_batch(batch: RetrievalBatch, k: int) -> RetrievalBatch:
@@ -462,11 +484,11 @@ class DirectBackprop:
 
         def loss_fn(p):
             q, pp, ph = _encode_chunk(encoder, p, batch)
-            return source.loss(q, pp, ph, carry, cfg=cfg, ctx=ctx, backend=backend)
+            return _loss(source, q, pp, ph, carry, cfg=cfg, ctx=ctx, backend=backend)
 
         (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         grads = ctx.psum_tree(grads)
-        carry = source.push(carry, aux, step, cfg=cfg, ctx=ctx)
+        carry = _push(source, carry, aux, step, cfg=cfg, ctx=ctx)
         return grads, aux, carry
 
 
@@ -491,18 +513,20 @@ class ScanAccumulate:
 
             def loss_fn(p):
                 q, pp, ph = _encode_chunk(encoder, p, chunk)
-                return source.loss(
-                    q, pp, ph, carry_, cfg=cfg, ctx=ctx, backend=backend
+                return _loss(
+                    source, q, pp, ph, carry_, cfg=cfg, ctx=ctx, backend=backend
                 )
 
             (_, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            carry_ = source.push(carry_, aux, step, cfg=cfg, ctx=ctx)
-            return (tree_add(grads_acc, g), carry_), aux
+            carry_ = _push(source, carry_, aux, step, cfg=cfg, ctx=ctx)
+            return (_accumulate(grads_acc, g), carry_), aux
 
         (grads, carry), auxs = jax.lax.scan(
             body, (tree_zeros_like(params), carry), chunks
         )
-        grads = ctx.psum_tree(tree_scale(grads, 1.0 / k))
+        with jax.named_scope("grad_accum"):
+            grads = tree_scale(grads, 1.0 / k)
+        grads = ctx.psum_tree(grads)
         return grads, _reduce_scanned_aux(auxs), carry
 
 
@@ -549,7 +573,8 @@ class RepCacheVJP:
         # Stage 2: d loss / d representations (the "gradient cache"), with
         # the source's extra columns/rows in the matrix.
         def rep_loss(q_all, pp_all, ph_all):
-            return source.loss(
+            return _loss(
+                source,
                 q_all,
                 pp_all,
                 ph_all if has_hard else None,
@@ -584,13 +609,13 @@ class RepCacheVJP:
                 g.astype(o.dtype) for g, o in zip((gq_k, gpp_k, gph_k), outs)
             )
             (g,) = vjp_fn(seeds)
-            return tree_add(grads_acc, g), None
+            return _accumulate(grads_acc, g), None
 
         grads, _ = jax.lax.scan(
             bwd, tree_zeros_like(params), (chunks, (gq, gpp, gph))
         )
         grads = ctx.psum_tree(grads)
-        carry = source.push(carry, aux, step, cfg=cfg, ctx=ctx)
+        carry = _push(source, carry, aux, step, cfg=cfg, ctx=ctx)
         return grads, aux, carry
 
 
@@ -720,8 +745,9 @@ def _metrics(
 
 
 def _apply(state: ContrastiveState, grads, tx, bank_q, bank_p) -> ContrastiveState:
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = apply_updates(state.params, updates)
+    with jax.named_scope("optimizer"):
+        updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        params = apply_updates(state.params, updates)
     return ContrastiveState(
         step=state.step + 1,
         params=params,

@@ -200,16 +200,17 @@ class Retriever:
         so ties break toward the lowest global id, matching replicated."""
         q, k = scores.shape
         d = self.shards
-        buf_s = jnp.zeros((q, d, k), scores.dtype)
-        buf_i = jnp.zeros((q, d, k), ids.dtype)
-        start = (0, shard_index, 0)
-        buf_s = jax.lax.dynamic_update_slice(buf_s, scores[:, None, :], start)
-        buf_i = jax.lax.dynamic_update_slice(buf_i, ids[:, None, :], start)
-        cat_s = ctx.psum(buf_s).reshape(q, d * k)
-        cat_i = ctx.psum(buf_i).reshape(q, d * k)
-        top_s, pos = jax.lax.top_k(cat_s, k)
-        top_i = jnp.take_along_axis(cat_i, pos, axis=1)
-        return top_s, jnp.where(top_s > NEG_INF / 2, top_i, -1)
+        with jax.named_scope("shard_merge"):
+            buf_s = jnp.zeros((q, d, k), scores.dtype)
+            buf_i = jnp.zeros((q, d, k), ids.dtype)
+            start = (0, shard_index, 0)
+            buf_s = jax.lax.dynamic_update_slice(buf_s, scores[:, None, :], start)
+            buf_i = jax.lax.dynamic_update_slice(buf_i, ids[:, None, :], start)
+            cat_s = ctx.psum(buf_s).reshape(q, d * k)
+            cat_i = ctx.psum(buf_i).reshape(q, d * k)
+            top_s, pos = jax.lax.top_k(cat_s, k)
+            top_i = jnp.take_along_axis(cat_i, pos, axis=1)
+            return top_s, jnp.where(top_s > NEG_INF / 2, top_i, -1)
 
     def _build_search(self, encode: bool):
         cfg = self.cfg

@@ -89,12 +89,13 @@ class DenseSearchBackend:
             ids = jnp.where(vld, ids, -1)
             # running best first: ties break toward earlier column blocks,
             # matching lax.top_k over the full row
-            cat_s = jnp.concatenate([best_s, s], axis=1)
-            cat_i = jnp.concatenate(
-                [best_i, jnp.broadcast_to(ids[None, :], s.shape)], axis=1
-            )
-            top_s, pos = jax.lax.top_k(cat_s, k)
-            return (top_s, jnp.take_along_axis(cat_i, pos, axis=1)), None
+            with jax.named_scope("block_topk"):
+                cat_s = jnp.concatenate([best_s, s], axis=1)
+                cat_i = jnp.concatenate(
+                    [best_i, jnp.broadcast_to(ids[None, :], s.shape)], axis=1
+                )
+                top_s, pos = jax.lax.top_k(cat_s, k)
+                return (top_s, jnp.take_along_axis(cat_i, pos, axis=1)), None
 
         init = (
             jnp.full((q, k), NEG_INF, SCORE_DTYPE),

@@ -15,6 +15,7 @@ checkpointed artifact.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import queue
 import threading
@@ -66,9 +67,30 @@ class Request:
     t_enqueue: float = dataclasses.field(default_factory=time.monotonic)
 
 
+class Ring(collections.deque):
+    """A bounded record of the newest ``maxlen`` entries that also takes
+    slices, as the lists the counters were before it did."""
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return list(self)[i]
+        return super().__getitem__(i)
+
+
 class BatchingServer:
     """Dynamic batcher: coalesce requests to ``max_batch`` (padding to the
-    compiled batch size) or flush after ``max_wait_s``."""
+    compiled batch size) or flush after ``max_wait_s``.
+
+    Counters, each a ``Ring`` with one entry for each of the newest
+    ``RECORD`` batches, so the two drop together: ``batch_sizes``, the
+    requests in the batch; ``queue_wait_s``, a tuple with, for each of its
+    requests, the seconds from ``submit`` until the batch was collected
+    (the coalescing window included), that is until its search began.
+    Each batch runs under the host spans ``repro.server.collect``,
+    ``repro.server.search`` and ``repro.server.deliver`` of a JAX profiler
+    trace."""
+
+    RECORD = 65536
 
     def __init__(
         self,
@@ -83,7 +105,8 @@ class BatchingServer:
         self._q: "queue.Queue[Request]" = queue.Queue()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        self.batch_sizes: List[int] = []   # observability: coalescing histogram
+        self.batch_sizes: Ring = Ring(maxlen=self.RECORD)
+        self.queue_wait_s: Ring = Ring(maxlen=self.RECORD)
 
     def start(self):
         self._thread = threading.Thread(target=self._loop, daemon=True)
@@ -138,10 +161,13 @@ class BatchingServer:
 
     def _loop(self):
         while not self._stop.is_set():
-            batch = self._collect()
+            with jax.profiler.TraceAnnotation("repro.server.collect"):
+                batch = self._collect()
             if not batch:
                 continue
+            t_collected = time.monotonic()
             self.batch_sizes.append(len(batch))
+            self.queue_wait_s.append(tuple(t_collected - r.t_enqueue for r in batch))
             payloads = np.stack([r.payload for r in batch])
             n = len(batch)
             if n < self.max_batch:  # pad to the compiled shape
@@ -149,10 +175,12 @@ class BatchingServer:
                     [payloads, np.repeat(payloads[-1:], self.max_batch - n, axis=0)]
                 )
             try:
-                ids, scores = self.serve_fn(payloads)
-                ids, scores = np.asarray(ids), np.asarray(scores)
-                for i, r in enumerate(batch):
-                    r.future.put((ids[i], scores[i]))
+                with jax.profiler.TraceAnnotation("repro.server.search"):
+                    ids, scores = self.serve_fn(payloads)
+                    ids, scores = np.asarray(ids), np.asarray(scores)
+                with jax.profiler.TraceAnnotation("repro.server.deliver"):
+                    for i, r in enumerate(batch):
+                        r.future.put((ids[i], scores[i]))
             except Exception as e:  # pragma: no cover - surfaced to callers
                 for r in batch:
                     r.future.put(e)

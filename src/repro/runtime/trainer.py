@@ -16,6 +16,12 @@ jit-compiled — see core/methods.py and launch/steps.py):
   * **Preemption handling** — ``request_stop()`` (wire to SIGTERM in the
     launcher) finishes the current step, writes a final checkpoint, exits
     cleanly.
+  * **Profiler spans** — each step is a ``StepTraceAnnotation("train")``
+    (the step boundaries of TensorBoard's step-time graph, and the step
+    seconds bench/harness/layers.py reads) holding the host spans
+    ``repro.train.next_batch``, ``repro.train.update`` and
+    ``repro.train.fetch``, on the clock of a JAX profiler trace; with no
+    profiler running they cost about a microsecond a step.
 
 The trainer is deliberately agnostic of what the step computes: it takes
 ``step_fn(state, batch) -> (state, metrics)`` plus a ``next_batch()``
@@ -213,11 +219,15 @@ class Trainer:
             try:
                 if self.fault_hook is not None:
                     self.fault_hook(step)  # may raise (injected fault)
-                batch = self.next_batch(step)
-                t0 = self.clock()
-                state, metrics = self.step_fn(state, batch)
-                metrics = jax.device_get(metrics)
-                dt = self.clock() - t0
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    with jax.profiler.TraceAnnotation("repro.train.next_batch"):
+                        batch = self.next_batch(step)
+                    t0 = self.clock()
+                    with jax.profiler.TraceAnnotation("repro.train.update"):
+                        state, metrics = self.step_fn(state, batch)
+                    with jax.profiler.TraceAnnotation("repro.train.fetch"):
+                        metrics = jax.device_get(metrics)
+                    dt = self.clock() - t0
 
                 if cfg.abort_on_nan:
                     loss = float(np.asarray(getattr(metrics, "loss", metrics.get("loss", 0.0)) if isinstance(metrics, dict) else metrics.loss))

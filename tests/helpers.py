@@ -48,3 +48,18 @@ def make_batch(rng, batch_size: int, dim_in: int = 16, n_hard: int = 0) -> Retri
     if n_hard > 0:
         hard = q[:, None, :] + 1.5 * jax.random.normal(kh, (batch_size, n_hard, dim_in))
     return RetrievalBatch(query=q, passage_pos=p, passage_hard=hard)
+
+
+def op_name_scopes(hlo_text: str, scopes) -> dict:
+    """{op_name: [the named scopes among ``scopes`` in its path]} over the
+    ``metadata={op_name=...}`` of a compiled program's HLO text. A path
+    component names a scope as ``towers`` does, or wrapped by
+    differentiation, as ``jvp(towers)`` and ``transpose(jvp(towers))``."""
+    import re
+
+    pats = {s: re.compile(r"(?:[\w]+\()*%s\)*" % re.escape(s)) for s in scopes}
+    out = {}
+    for name in set(re.findall(r'op_name="([^"]*)"', hlo_text)):
+        parts = re.split(r"[/;]", name)
+        out[name] = [s for s in scopes for c in parts if pats[s].fullmatch(c)]
+    return out

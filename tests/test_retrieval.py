@@ -183,7 +183,7 @@ def test_batching_server_coalesces_backlog():
     try:
         for f in futs:
             f.get(timeout=10)
-        assert srv.batch_sizes == [8, 8, 8, 8], srv.batch_sizes
+        assert list(srv.batch_sizes) == [8, 8, 8, 8], srv.batch_sizes
     finally:
         srv.stop()
 
@@ -385,6 +385,7 @@ SHARDED_SCRIPT = textwrap.dedent(
 
     import sys
     sys.path.insert(0, "tests")
+    from helpers import op_name_scopes
     from test_retrieval import _VecCorpus, _mlp_encoder
     from repro.retrieval import Retriever, RetrieverConfig, make_dp_mesh
 
@@ -420,6 +421,15 @@ SHARDED_SCRIPT = textwrap.dedent(
         # sharded must match replicated bit-for-bit: ids AND scores
         np.testing.assert_array_equal(ids_r, ids_s, err_msg=impl)
         np.testing.assert_array_equal(s_r, s_s, err_msg=impl)
+        # the merge across shards is its own named scope in the program
+        text = sh._search_tokens.lower(
+            sh.params, sh.index.reps, sh.index.row_valid,
+            jnp.asarray(corpus.queries[:17]),
+        ).compile().as_text()
+        found = op_name_scopes(text, ("block_topk", "shard_merge"))
+        want = {"shard_merge", "block_topk"} if impl == "dense" else {"shard_merge"}
+        assert {s for v in found.values() for s in v} == want, found
+        assert all(len(v) <= 1 for v in found.values()), found
         print(f"{precision}/{impl}: OK")
     print("SHARDED-PARITY-OK")
     """
