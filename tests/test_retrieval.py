@@ -94,6 +94,122 @@ def test_backend_k_exceeds_valid_columns(impl, kw):
                                np.asarray(ref_s)[:, :4], atol=1e-5)
 
 
+def _merge_case(case):
+    """(q, p, col_valid or None, k, block) for the two-stage merge cases:
+    9,000 rows, so the last 4,096-row block ends in a partly masked group."""
+    rng = np.random.default_rng(4)
+    n, valid = 9000, None
+    q = rng.integers(-2, 3, size=(7, 8)).astype(np.float32)
+    p = rng.integers(-2, 3, size=(n, 8)).astype(np.float32)
+    k, block = 16, 4096
+    if case == "random":
+        q, p = _rand(13, n, 24, seed=5)
+        k = 10
+    elif case == "duplicate_rows":       # ties inside groups, across groups and blocks
+        p[[50, 60, 300, 4200, 8300]] = p[10]
+    elif case == "ties_past_k_groups":   # every group of every block holds the k-th value
+        q, p = np.ones((3, 8), np.float32), np.ones((n, 8), np.float32)
+        p[5000] = 2.0
+    elif case == "masked_groups":        # scattered masks, whole groups, a whole block's head
+        valid = rng.random(n) > 0.3
+        valid[256:1024] = False
+        valid[4096:4096 + 1000] = False
+        k = 10
+    elif case == "k_past_valid":
+        valid = np.zeros(n, bool)
+        valid[[5, 700, 4100, 8999]] = True
+    elif case == "fallback":             # 4·k·32 > 3·block: one top_k a block
+        k, block = 60, 2048
+    return q, p, valid, k, block
+
+
+@pytest.mark.parametrize("case", [
+    "random", "duplicate_rows", "ties_past_k_groups", "masked_groups",
+    "k_past_valid", "fallback",
+])
+def test_dense_two_stage_merge_is_exact(case, monkeypatch):
+    """The two-stage block merge (group maxima, k groups, top_k over their
+    columns) selects what one top_k over each block does, scores to the
+    last bit, and what the full (Q, N) reference does: ids exactly,
+    lowest-id ties, -1 for empty slots."""
+    from repro.retrieval import search
+
+    q, p, valid, k, block = (jnp.asarray(x) if isinstance(x, np.ndarray) else x
+                             for x in _merge_case(case))
+    be = DenseSearchBackend(block=block)
+    assert (search.merge_group(block, k) is None) == (case == "fallback")
+
+    def run():
+        return [np.asarray(x) for x in
+                jax.jit(lambda a, b: be.topk(a, b, k, col_valid=valid))(q, p)]
+
+    s, i = run()
+    ref_s, ref_i = topk_scores_ref(q, p, k, col_valid=valid)
+    monkeypatch.setattr(search, "merge_group", lambda block, k: None)
+    one_s, one_i = run()
+    np.testing.assert_array_equal(i, np.asarray(ref_i))
+    if case == "random":   # the reference's one (Q, N) matmul rounds apart
+        np.testing.assert_allclose(s, np.asarray(ref_s), rtol=0, atol=1e-5)
+    else:                  # integer scores: exact in any summation order
+        np.testing.assert_array_equal(s, np.asarray(ref_s))
+    np.testing.assert_array_equal(i, one_i)
+    np.testing.assert_array_equal(s, one_s)
+    if case == "ties_past_k_groups":
+        np.testing.assert_array_equal(i[:, 0], 5000)
+        np.testing.assert_array_equal(i[:, 1:], np.tile(np.arange(k - 1), (3, 1)))
+    if case == "k_past_valid":
+        np.testing.assert_array_equal(np.sort(i[:, :4], axis=1),
+                                      np.tile([5, 700, 4100, 8999], (7, 1)))
+        assert np.all(i[:, 4:] == -1)
+
+
+def _sort_widths(hlo_text: str) -> list:
+    """The widest dimension of each operand of the sorts and TopK custom
+    calls in a compiled program's HLO text."""
+    import re
+
+    shapes = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo_text))
+    widths = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"= .*? (?:sort|custom-call)\(([^)]*)\)", line)
+        if m and (" sort(" in line or '"TopK"' in line):
+            for name in re.findall(r"%([\w.-]+)", m.group(1)):
+                widths.append(max(int(x) for x in shapes[name].split(",")))
+    return widths
+
+
+@pytest.mark.parametrize("k,block,width,engaged", [
+    (100, 65536, 2048 + 100 + 100 * 32, True),    # the serving cells' shape
+    (16, 4096, 128 + 16 + 16 * 32, True),
+    (48, 2048, 64 + 48 + 48 * 32, True),         # 4·k·32 = 3·block: the last k in
+    (49, 2048, 2048 + 49, False),                 # and the first k out
+    (9, 16, 16 + 9, False),
+])
+def test_dense_merge_width_and_engagement(k, block, width, engaged):
+    """``merge_width`` counts the columns a block's merge sorts; where the
+    two-stage merge engages, no sort in the compiled search is as wide as
+    the block, and its three steps are named inside ``block_topk``."""
+    from helpers import op_name_scopes
+
+    be = DenseSearchBackend(block=block)
+    assert be.merge_width(k) == width
+    if block > 4096:
+        return
+    q, p = _rand(4, 2 * block + 7, 8)
+    text = jax.jit(lambda a, b: be.topk(a, b, k)).lower(
+        jnp.asarray(q), jnp.asarray(p)).compile().as_text()
+    widths = _sort_widths(text)
+    assert widths and (max(widths) < block) == engaged, widths
+    nested = ("group_max", "group_select", "candidate_topk")
+    found = op_name_scopes(text, ("block_topk",) + nested)
+    named = {s for v in found.values() for s in v}
+    assert named == ({"block_topk", *nested} if engaged else {"block_topk"}), named
+    # one tracked scope an op; the merge's parts only inside block_topk
+    nest = [["block_topk", m] for m in nested]
+    assert all(len(v) <= 1 and not set(v) & set(nested) or v in nest
+               for v in found.values()), found
+
+
 @pytest.mark.parametrize("k,bn", [
     (5, 16),      # k inside one tile
     (40, 16),     # k spans several tiles
@@ -392,11 +508,16 @@ SHARDED_SCRIPT = textwrap.dedent(
     assert jax.device_count() == 8
     enc = _mlp_encoder()
     params = enc.init(jax.random.PRNGKey(0))
-    corpus = _VecCorpus(n=93)        # 93 % 8 != 0: exercises row padding
     mesh = make_dp_mesh(8)
+    merge = ("group_max", "group_select", "candidate_topk")
 
-    for precision, impl in (("fp32", "dense"), ("bf16_banks", "fused")):
-        rcfg = dict(top_k=9, precision=precision, score_block=16,
+    # 93 and 4,805 rows (% 8 != 0) exercise row padding; blocks of 576
+    # rows a device (9·32 <= 3/4 of 576) engage the two-stage block merge
+    for precision, impl, block, n in (("fp32", "dense", 16, 93),
+                                      ("bf16_banks", "fused", 16, 93),
+                                      ("fp32", "dense", 576, 4805)):
+        corpus = _VecCorpus(n=n)
+        rcfg = dict(top_k=9, precision=precision, score_block=block,
                     block_q=8, block_n=16, search_impl=impl)
         rep = Retriever(enc, params, RetrieverConfig(**rcfg))
         sh = Retriever(
@@ -426,11 +547,16 @@ SHARDED_SCRIPT = textwrap.dedent(
             sh.params, sh.index.reps, sh.index.row_valid,
             jnp.asarray(corpus.queries[:17]),
         ).compile().as_text()
-        found = op_name_scopes(text, ("block_topk", "shard_merge"))
+        found = op_name_scopes(text, ("block_topk", "shard_merge") + merge)
         want = {"shard_merge", "block_topk"} if impl == "dense" else {"shard_merge"}
+        if block > 16:
+            want |= set(merge)
         assert {s for v in found.values() for s in v} == want, found
-        assert all(len(v) <= 1 for v in found.values()), found
-        print(f"{precision}/{impl}: OK")
+        # one tracked scope an op; the merge's parts only inside block_topk
+        nest = [["block_topk", m] for m in merge]
+        assert all(len(v) <= 1 and not set(v) & set(merge) or v in nest
+                   for v in found.values()), found
+        print(f"{precision}/{impl}/{block}: OK")
     print("SHARDED-PARITY-OK")
     """
 )

@@ -2,8 +2,9 @@
 
 The TPU compiler is installed with JAX, so it can compile for a ``v5e:2x2``
 topology that is described, not attached. These tests compile the main
-path's Pallas kernels and the dpr-bert-base ContAccum step at real widths
-and check what the chip's compiler would refuse: a kernel that does not
+path's Pallas kernels, the dense search and the dpr-bert-base ContAccum
+step at real widths and check what the chip's compiler would refuse, or
+how it lowers the search's sorts: a kernel that does not
 lower (it must appear as ``tpu_custom_call``, not an XLA fallback) and a
 step that does not fit one chip's 16 GB. Nothing runs, so nothing here is
 a result or a time.
@@ -25,6 +26,7 @@ from repro.core.types import RetrievalBatch
 from repro.kernels.fused_infonce.ops import fused_infonce_stats
 from repro.kernels.fused_topk.ops import fused_topk_scores
 from repro.launch import train
+from repro.retrieval import DenseSearchBackend
 
 V5E_HBM_BYTES = 16e9
 
@@ -104,6 +106,32 @@ def test_fused_topk_compiles_for_v5e(one_chip):
     assert _kernel_count(compiled) >= 1
     scores, ids = compiled.out_info
     assert scores.shape == ids.shape == (32, 100)
+
+
+@pytest.mark.parametrize("k", [100, 512])
+def test_dense_search_two_stage_merge_compiles_for_v5e(one_chip, k):
+    """The serving cells' search shape (Q 32, blocks of 65,536 rows, k 100),
+    and k 512, take the two-stage block merge: the chip's compiler sorts no
+    operand as wide as a block, and every sort it emits is stable, so ties
+    still break toward the lowest id. (One ``top_k`` over a whole block at
+    k 512 compiles to sorts that compare the score alone and are not
+    stable.)"""
+    from test_retrieval import _sort_widths
+
+    be = DenseSearchBackend()
+    q = _shape(one_chip, (32, 768), jnp.bfloat16)
+    index = _shape(one_chip, (2 * be.block, 768), jnp.bfloat16)
+    valid = _shape(one_chip, (2 * be.block,), jnp.bool_)
+    compiled = jax.jit(
+        lambda q_, p_, v_: be.topk(q_, p_, k, col_valid=v_)
+    ).lower(q, index, valid).compile()
+    text = compiled.as_text()
+    widths = _sort_widths(text)
+    assert widths and max(widths) < be.block, widths
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert sorts and all("is_stable=true" in line for line in sorts), sorts
+    scores, ids = compiled.out_info
+    assert scores.shape == ids.shape == (32, k)
 
 
 @pytest.mark.parametrize("loss_impl", ["dense", "fused"])

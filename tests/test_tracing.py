@@ -49,16 +49,24 @@ def test_search_names_block_topk():
     from test_retrieval import _VecCorpus, _mlp_encoder
 
     enc = _mlp_encoder()
-    corpus = _VecCorpus(n=93)
-    r = Retriever(enc, enc.init(jax.random.PRNGKey(0)),
-                  RetrieverConfig(top_k=9, score_block=16, search_impl="dense"))
-    r.build_index(corpus.passages)
-    r.search(corpus.queries[:5])
-    text = r._search_tokens.lower(r.params, r.index.reps, r.index.row_valid,
-                                  jnp.asarray(corpus.queries[:5])).compile().as_text()
-    found = op_name_scopes(text, ("block_topk", "shard_merge"))
-    assert {s for v in found.values() for s in v} == {"block_topk"}
-    assert all(len(v) <= 1 for v in found.values())
+    merge = ("group_max", "group_select", "candidate_topk")
+    # one top_k a block; then blocks of 576 columns (9·32 <= 3/4 of 576),
+    # where the two-stage merge's steps are named inside block_topk
+    for block, n, want in ((16, 93, {"block_topk"}),
+                           (576, 600, {"block_topk", *merge})):
+        corpus = _VecCorpus(n=n)
+        r = Retriever(enc, enc.init(jax.random.PRNGKey(0)),
+                      RetrieverConfig(top_k=9, score_block=block, search_impl="dense"))
+        r.build_index(corpus.passages)
+        r.search(corpus.queries[:5])
+        text = r._search_tokens.lower(r.params, r.index.reps, r.index.row_valid,
+                                      jnp.asarray(corpus.queries[:5])).compile().as_text()
+        found = op_name_scopes(text, ("block_topk", "shard_merge") + merge)
+        assert {s for v in found.values() for s in v} == want
+        # one tracked scope an op; the merge's parts only inside block_topk
+        nest = [["block_topk", m] for m in merge]
+        assert all(len(v) <= 1 and not set(v) & set(merge) or v in nest
+                   for v in found.values()), found
 
 
 def test_ring_keeps_the_newest_entries():
