@@ -19,6 +19,7 @@ from repro.configs import (  # noqa: F401
     deepfm,
     dlrm_mlperf,
     dlrm_rm2,
+    moonlight_16b_a3b,
 )
 
 TINY_BERT = "bert-tiny"
@@ -42,7 +43,18 @@ def bert_archs():
     return [TINY_BERT] + [a for a in list_archs() if get_arch(a).family == "bert"]
 
 
+def lm_tower_archs():
+    """Registered LM backbones built as retriever towers (no LM head)."""
+    return [a for a in list_archs()
+            if get_arch(a).family == "lm" and not get_arch(a).model_cfg.head]
+
+
+def tower_archs():
+    """Every ``--arch`` a training driver can build a dual encoder from."""
+    return bert_archs() + lm_tower_archs()
+
+
 __all__ = [
     "ArchSpec", "ShapeCell", "get_arch", "register", "list_archs",
-    "TINY_BERT", "bert_tower", "bert_archs",
+    "TINY_BERT", "bert_tower", "bert_archs", "lm_tower_archs", "tower_archs",
 ]

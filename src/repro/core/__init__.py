@@ -19,7 +19,6 @@ from repro.core.dist import DistCtx
 from repro.core.precision import (
     PRECISION_PRESETS,
     PrecisionPolicy,
-    apply_compute_dtype,
     bank_bytes_per_device,
     resolve_precision,
 )
@@ -66,7 +65,7 @@ __all__ = [
     "LossBackend", "DenseLossBackend", "FusedLossBackend", "LOSS_BACKENDS",
     "resolve_loss_backend",
     "DistCtx",
-    "PRECISION_PRESETS", "PrecisionPolicy", "apply_compute_dtype",
+    "PRECISION_PRESETS", "PrecisionPolicy",
     "bank_bytes_per_device", "resolve_precision",
     "ContrastiveConfig", "ContrastiveState", "DualEncoder", "RetrievalBatch",
     "StepMetrics", "chunk_tree", "flatten_hard",

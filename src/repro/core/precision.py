@@ -112,49 +112,6 @@ def resolve_precision(
     return spec
 
 
-def apply_compute_dtype(encoder, policy: Union[str, PrecisionPolicy]):
-    """Wrap a DualEncoder so params are cast to ``compute_dtype`` at
-    application and the emitted representations are in ``compute_dtype``.
-
-    The BERT towers honor a policy natively (``BertConfig.with_precision``);
-    this generic wrapper gives every other encoder — including the tiny MLP
-    test towers — the same mixed-precision semantics: stored params stay in
-    ``param_dtype`` (fp32 masters), transient compute copies are created per
-    application, float inputs are cast alongside. Identity under fp32.
-    """
-    from repro.core.types import DualEncoder
-
-    policy = resolve_precision(policy)
-    ct = policy.compute_dtype
-
-    def _cast_tree(tree):
-        return jax.tree_util.tree_map(
-            lambda a: a.astype(ct) if jnp.issubdtype(a.dtype, jnp.floating) else a,
-            tree,
-        )
-
-    def encode_query(params, batch):
-        return encoder.encode_query(_cast_tree(params), _cast_tree(batch)).astype(ct)
-
-    def encode_passage(params, batch):
-        return encoder.encode_passage(_cast_tree(params), _cast_tree(batch)).astype(ct)
-
-    def init(rng, *a, **kw):
-        return jax.tree_util.tree_map(
-            lambda p: p.astype(policy.param_dtype)
-            if jnp.issubdtype(p.dtype, jnp.floating)
-            else p,
-            encoder.init(rng, *a, **kw),
-        )
-
-    return DualEncoder(
-        init=init,
-        encode_query=encode_query,
-        encode_passage=encode_passage,
-        rep_dim=encoder.rep_dim,
-    )
-
-
 def bank_bytes_per_device(
     capacity_q: int,
     capacity_p: int,

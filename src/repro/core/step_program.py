@@ -81,7 +81,7 @@ tests/test_ring_parity.py) at O(N_mem*d/D) transient memory per eval.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Protocol, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Protocol, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -407,8 +407,10 @@ class BackpropStrategy(Protocol):
         step: jnp.ndarray,
         cfg: ContrastiveConfig,
         ctx: DistCtx,
-    ) -> Tuple[Any, LossAux, Carry]:
-        """Returns (psum'ed grads, reduced aux, final carry)."""
+    ) -> Tuple[Any, LossAux, Carry, dict]:
+        """Returns (psum'ed grads, reduced aux, final carry, the encoder's
+        counters summed over the update). A shared tower's grads come as
+        ``Sides``."""
         ...
 
 
@@ -418,14 +420,67 @@ class BackpropStrategy(Protocol):
 # Differentiation keeps the name, as ``jvp(towers)`` and
 # ``transpose(jvp(towers))``. They are metadata only: the compiled code and
 # the compilation cache's key do not change.
-def _encode_chunk(encoder: DualEncoder, params, chunk: RetrievalBatch):
+def _encode(encoder: DualEncoder, params, side: str, batch):
+    """(reps, counters) of one encode call; counters are {} for encoders
+    that keep none."""
+    if encoder.encode_counters is not None:
+        return encoder.encode_counters(params, side, batch)
+    fn = encoder.encode_query if side == "query" else encoder.encode_passage
+    return fn(params, batch), {}
+
+
+def _add_counters(*counters):
+    counters = [c for c in counters if c]
+    if not counters:
+        return {}
+    return jax.tree_util.tree_map(lambda *xs: sum(xs[1:], xs[0]), *counters)
+
+
+def _encode_chunk(encoder: DualEncoder, params, chunk: RetrievalBatch, q=None):
+    """(q, pp, ph, counters) of one chunk. A given ``q`` (the shared
+    tower's query side, encoded apart) is used as is. A shared tower
+    encodes the positives and the hard negatives in one call, so that one
+    backward pass, and one gradient of the backbone, serves both."""
     with jax.named_scope("towers"):
-        q = encoder.encode_query(params, chunk.query)
-        pp = encoder.encode_passage(params, chunk.passage_pos)
-        ph = None
+        cq = {}
+        if q is None:
+            q, cq = _encode(encoder, params, "query", chunk.query)
+        if encoder.shared and chunk.passage_hard is not None:
+            n = jax.tree_util.tree_leaves(chunk.passage_pos)[0].shape[0]
+            both = jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b]),
+                                          chunk.passage_pos, flatten_hard(chunk.passage_hard))
+            reps, cp = _encode(encoder, params, "passage", both)
+            return q, reps[:n], reps[n:], _add_counters(cq, cp)
+        pp, cp = _encode(encoder, params, "passage", chunk.passage_pos)
+        ph, ch = None, {}
         if chunk.passage_hard is not None:
-            ph = encoder.encode_passage(params, flatten_hard(chunk.passage_hard))
-        return q, pp, ph
+            ph, ch = _encode(encoder, params, "passage", flatten_hard(chunk.passage_hard))
+        return q, pp, ph, _add_counters(cq, cp, ch)
+
+
+# A shared tower's gradient is one tree, the sum of the two sides'
+# contributions; the strategies hand it over split, as Sides, so that the
+# metrics can give each side's norm. The query side is taken apart from the
+# passage side: the passage side's gradient is taken with the query
+# encodings as inputs, their cotangents kept (one row per query), and the
+# query side is one VJP through the query encodings of the whole batch,
+# seeded with them. That recomputes the queries' forward once and holds no
+# second gradient tree across the chunks.
+class Sides(NamedTuple):
+    query: Any
+    passage: Any
+
+
+def _query_side(encoder: DualEncoder, params, queries, dq):
+    """The query side's gradient: d(loss)/d(query encodings) ``dq``, pulled
+    back through the encodings of ``queries``."""
+    def enc(p):
+        with jax.named_scope("towers"):
+            return _encode(encoder, p, "query", queries)[0]
+
+    out, vjp_fn = jax.vjp(enc, params)
+    (g,) = vjp_fn(dq.astype(out.dtype))
+    return g
 
 
 def _loss(source: NegativeSource, q, pp, ph, carry, **kw):
@@ -471,6 +526,34 @@ def _reduce_scanned_aux(auxs: LossAux) -> LossAux:
     )
 
 
+def _chunk_grads(encoder, params, chunk, source, carry, cfg, ctx, backend):
+    """((aux, counters), grads, dq) of one loss evaluation. For a shared
+    tower ``grads`` is the passage side's alone and ``dq`` the loss's
+    cotangent of the query encodings (see ``Sides``); otherwise ``dq`` is
+    None."""
+    q = None
+    cq = {}
+    if encoder.shared:
+        with jax.named_scope("towers"):
+            q, cq = _encode(encoder, params, "query", chunk.query)
+
+    def loss_fn(p, q_in):
+        q_, pp, ph, counters = _encode_chunk(encoder, p, chunk, q_in)
+        loss, aux = _loss(source, q_, pp, ph, carry, cfg=cfg, ctx=ctx, backend=backend)
+        return loss, (aux, _add_counters(cq, counters))
+
+    if encoder.shared:
+        (_, out), (g, dq) = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(params, q)
+        return out, g, dq
+    (_, out), g = jax.value_and_grad(loss_fn, has_aux=True)(params, None)
+    return out, g, None
+
+
+def _sum_counters(counters):
+    """Counters stacked over the chunks -> summed over the update."""
+    return jax.tree_util.tree_map(lambda x: x.sum(0), counters)
+
+
 class DirectBackprop:
     """One forward/backward over the whole batch (full activation memory)."""
 
@@ -481,15 +564,13 @@ class DirectBackprop:
 
     def compute(self, encoder, params, batch, source, carry, step, cfg, ctx):
         backend = resolve_loss_backend(cfg.loss_impl)
-
-        def loss_fn(p):
-            q, pp, ph = _encode_chunk(encoder, p, batch)
-            return _loss(source, q, pp, ph, carry, cfg=cfg, ctx=ctx, backend=backend)
-
-        (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        (aux, counters), grads, dq = _chunk_grads(
+            encoder, params, batch, source, carry, cfg, ctx, backend)
+        if encoder.shared:
+            grads = Sides(_query_side(encoder, params, batch.query, dq), grads)
         grads = ctx.psum_tree(grads)
         carry = _push(source, carry, aux, step, cfg=cfg, ctx=ctx)
-        return grads, aux, carry
+        return grads, aux, carry, counters
 
 
 class ScanAccumulate:
@@ -510,24 +591,21 @@ class ScanAccumulate:
 
         def body(c, chunk):
             grads_acc, carry_ = c
-
-            def loss_fn(p):
-                q, pp, ph = _encode_chunk(encoder, p, chunk)
-                return _loss(
-                    source, q, pp, ph, carry_, cfg=cfg, ctx=ctx, backend=backend
-                )
-
-            (_, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            (aux, counters), g, dq = _chunk_grads(
+                encoder, params, chunk, source, carry_, cfg, ctx, backend)
             carry_ = _push(source, carry_, aux, step, cfg=cfg, ctx=ctx)
-            return (_accumulate(grads_acc, g), carry_), aux
+            return (_accumulate(grads_acc, g), carry_), (aux, counters, dq)
 
-        (grads, carry), auxs = jax.lax.scan(
+        (grads, carry), (auxs, counters, dqs) = jax.lax.scan(
             body, (tree_zeros_like(params), carry), chunks
         )
         with jax.named_scope("grad_accum"):
             grads = tree_scale(grads, 1.0 / k)
+        if encoder.shared:
+            dq = dqs.reshape((-1,) + dqs.shape[2:]) / k
+            grads = Sides(_query_side(encoder, params, batch.query, dq), grads)
         grads = ctx.psum_tree(grads)
-        return grads, _reduce_scanned_aux(auxs), carry
+        return grads, _reduce_scanned_aux(auxs), carry, _sum_counters(counters)
 
 
 class RepCacheVJP:
@@ -555,11 +633,11 @@ class RepCacheVJP:
         # activations for the loss graph (stop_gradient == GradCache's
         # torch.no_grad forward).
         def fwd(_, chunk):
-            q, pp, ph = _encode_chunk(encoder, params, chunk)
+            q, pp, ph, counters = _encode_chunk(encoder, params, chunk)
             ph = jnp.zeros((0, q.shape[-1]), q.dtype) if ph is None else ph
-            return None, (q, pp, ph)
+            return None, ((q, pp, ph), counters)
 
-        _, (qs, pps, phs) = jax.lax.scan(fwd, None, chunks)
+        _, ((qs, pps, phs), counters) = jax.lax.scan(fwd, None, chunks)
         # the cached representation store lives in the policy's compute dtype
         # (bf16 halves the (B_g + banks, d) cache this strategy carries)
         pol = cfg.resolved_precision()
@@ -597,26 +675,29 @@ class RepCacheVJP:
             chunk, (gq_k, gpp_k, gph_k) = inp
 
             def enc(p):
-                q, pp, ph = _encode_chunk(encoder, p, chunk)
+                # a shared tower's query side is pulled back apart (Sides)
+                q_in = gq_k if encoder.shared else None
+                q, pp, ph, _ = _encode_chunk(encoder, p, chunk, q_in)
                 ph = jnp.zeros((0, q.shape[-1]), q.dtype) if ph is None else ph
-                return (q, pp, ph)
+                return (pp, ph) if encoder.shared else (q, pp, ph)
 
             outs, vjp_fn = jax.vjp(enc, params)
             # cached cotangents are in compute dtype; the encoder's native
             # output dtype may differ (fp32 towers under a bf16 policy) —
             # seed the VJP in the primal dtype it expects
-            seeds = tuple(
-                g.astype(o.dtype) for g, o in zip((gq_k, gpp_k, gph_k), outs)
-            )
+            cots = (gpp_k, gph_k) if encoder.shared else (gq_k, gpp_k, gph_k)
+            seeds = tuple(g.astype(o.dtype) for g, o in zip(cots, outs))
             (g,) = vjp_fn(seeds)
             return _accumulate(grads_acc, g), None
 
         grads, _ = jax.lax.scan(
             bwd, tree_zeros_like(params), (chunks, (gq, gpp, gph))
         )
+        if encoder.shared:
+            grads = Sides(_query_side(encoder, params, batch.query, merge(gq)), grads)
         grads = ctx.psum_tree(grads)
         carry = _push(source, carry, aux, step, cfg=cfg, ctx=ctx)
-        return grads, aux, carry
+        return grads, aux, carry, _sum_counters(counters)
 
 
 # --------------------------------------------------------------------------
@@ -711,6 +792,19 @@ class StepProgram:
         return f"{self.source.name}*{self.strategy.name}"
 
 
+def _tower_metrics(counters: dict) -> dict:
+    """Scalar counters as they are; an array counter as its max and mean."""
+    out = {}
+    for name, v in counters.items():
+        v = jnp.asarray(v)
+        if v.ndim:
+            out[f"{name}_max"] = v.max().astype(STATS_DTYPE)
+            out[f"{name}_mean"] = v.mean(dtype=STATS_DTYPE)
+        else:
+            out[name] = v.astype(STATS_DTYPE)
+    return out
+
+
 def _metrics(
     grads,
     aux: LossAux,
@@ -719,9 +813,14 @@ def _metrics(
     *,
     ctx: Optional[DistCtx] = None,
     sharded_banks: bool = False,
+    sides: Optional[Sides] = None,
+    counters: Optional[dict] = None,
 ) -> StepMetrics:
-    gq = subtree_norm(grads, "query")
-    gp = subtree_norm(grads, "passage")
+    if sides is not None:
+        gq, gp = tree_global_norm(sides.query), tree_global_norm(sides.passage)
+    else:
+        gq = subtree_norm(grads, "query")
+        gp = subtree_norm(grads, "passage")
 
     def fill(bank: BankState) -> jnp.ndarray:
         if not bank.buf.shape[0]:
@@ -741,6 +840,7 @@ def _metrics(
         n_negatives=aux.n_negatives,
         bank_fill_q=fill(bank_q),
         bank_fill_p=fill(bank_p),
+        tower=_tower_metrics(counters or {}),
     )
 
 
@@ -775,14 +875,20 @@ def build_step_program(
 
     def update(state: ContrastiveState, batch: RetrievalBatch):
         carry = source.begin(state, cfg)
-        grads, aux, carry = strategy.compute(
+        grads, aux, carry, counters = strategy.compute(
             encoder, state.params, batch, source, carry, state.step, cfg, ctx
         )
+        sides = None
+        if isinstance(grads, Sides):
+            sides = grads
+            with jax.named_scope("grad_accum"):
+                grads = tree_add(sides.query, sides.passage)
         bank_q, bank_p = carry
         new_state = _apply(state, grads, tx, bank_q, bank_p)
         return new_state, _metrics(
             grads, aux, bank_q, bank_p,
             ctx=ctx, sharded_banks=cfg.shard_banks and ctx.is_distributed,
+            sides=sides, counters=counters,
         )
 
     return StepProgram(update=update, source=source, strategy=strategy, cfg=cfg)

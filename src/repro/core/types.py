@@ -25,14 +25,23 @@ class RetrievalBatch(NamedTuple):
 
 
 class DualEncoder(NamedTuple):
-    """Abstract dual encoder. ``params`` is expected to be a dict with keys
-    'query' and 'passage' (may alias for shared towers); the encode fns take
-    the full params dict."""
+    """Abstract dual encoder; the encode fns take the full params dict.
+
+    ``params`` is a dict with keys 'query' and 'passage', or, for a shared
+    tower (``shared=True``), the key 'shared' alone: one backbone, held once,
+    whose gradient is the sum of what the query side and the passage side
+    contribute (core/step_program.py). ``encode_counters(params, side,
+    batch) -> (reps, counters)``, where given, is the encode call the update
+    uses: ``side`` is 'query' or 'passage', and ``counters`` a dict of
+    arrays that the update sums over its encode calls and reports in
+    ``StepMetrics.tower``."""
 
     init: Callable[..., Any]                       # (rng, ...) -> params
     encode_query: Callable[[Any, Any], jnp.ndarray]    # (params, batch.query) -> (B, d)
     encode_passage: Callable[[Any, Any], jnp.ndarray]  # (params, passages) -> (B, d)
     rep_dim: int
+    shared: bool = False
+    encode_counters: Optional[Callable[[Any, str, Any], Any]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,6 +175,13 @@ class ContrastiveState(NamedTuple):
 
 
 class StepMetrics(NamedTuple):
+    """One update's metrics. For a shared tower, ``grad_norm_query`` and
+    ``grad_norm_passage`` are the norms of each side's contribution to the
+    one gradient (through the query encodings, through the passage
+    encodings), and ``grad_norm`` the norm of their sum. ``tower`` holds the
+    encoder's counters summed over the update (an array counter as its
+    ``_max`` and ``_mean``); empty for encoders without counters."""
+
     loss: jnp.ndarray
     accuracy: jnp.ndarray
     grad_norm: jnp.ndarray
@@ -175,6 +191,7 @@ class StepMetrics(NamedTuple):
     n_negatives: jnp.ndarray      # negatives per query row actually used
     bank_fill_q: jnp.ndarray
     bank_fill_p: jnp.ndarray
+    tower: Any = {}
 
 
 def subtree_norm(grads: Any, key: str) -> jnp.ndarray:

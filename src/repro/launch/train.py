@@ -15,6 +15,14 @@ registry, N_total=128, N_local=8, N_mem=2048, q_len 32, p_len 256) on a TPU:
       --method contaccum --total-batch 128 --local-batch 8 --bank 2048 \
       --q-len 32 --p-len 256 --precision bf16_banks --loss-impl fused
 
+An LM retriever tower through the same path: one shared Moonlight-16B-A3B
+backbone (one chip's share: 8 of its 64 experts, a fifth of its depth, an
+eighth of its vocabulary), mean-pooled:
+
+  PYTHONPATH=src python -m repro.launch.train --arch moonlight-16b-a3b-ep8 \
+      --method contaccum --total-batch 128 --local-batch 32 --bank 2048 \
+      --q-len 32 --p-len 256 --precision bf16_banks --lr 2e-5
+
 Data-parallel shard_map path (requires >= N devices, e.g.
 XLA_FLAGS=--xla_force_host_platform_device_count=8 on CPU): ``--dp N``
 shards the batch over an N-way mesh with cross-device in-batch negatives;
@@ -49,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import TINY_BERT, bert_archs, bert_tower
+from repro.configs import TINY_BERT, bert_tower, get_arch, lm_tower_archs, tower_archs
 from repro.core.methods import (
     available_methods,
     build_step_program,
@@ -63,7 +71,7 @@ from repro.core.types import ContrastiveConfig, RetrievalBatch
 from repro.data.loader import ShardedLoader
 from repro.data.retrieval import SyntheticRetrievalCorpus
 from repro.launch.compile_cache import setup_compile_cache
-from repro.models.towers import make_bert_dual_encoder
+from repro.models.towers import make_bert_dual_encoder, make_lm_dual_encoder
 from repro.optim.adamw import adamw, chain, clip_by_global_norm
 from repro.optim.schedules import linear_warmup_linear_decay
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -93,10 +101,12 @@ class TrainRun:
 
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=TINY_BERT, choices=bert_archs(),
+    ap.add_argument("--arch", default=TINY_BERT, choices=tower_archs(),
                     help="tower config from the registry (repro/configs): "
                          "bert-tiny for CPU runs, dpr-bert-base for the "
-                         "paper's bert-base-uncased towers")
+                         "paper's bert-base-uncased towers, or an LM "
+                         "backbone shared by both sides and mean-pooled "
+                         "(moonlight-16b-a3b-ep8; moonlight-tiny on CPU)")
     # mesh-requiring compositions can't build in this single-program driver;
     # only offer methods it can actually run
     methods = [m for m in available_methods() if not method_needs_mesh(m)]
@@ -213,13 +223,17 @@ def build_step(args) -> TrainStep:
         shard_banks=bool(args.shard_banks and dp and bank),
         loss_comm=args.loss_comm,
     )
-    tower = bert_tower(args.arch)
-    if max(args.q_len, args.p_len) > tower.max_position:
-        raise SystemExit(
-            f"--q-len/--p-len exceed {args.arch}'s max_position "
-            f"{tower.max_position}"
-        )
-    enc = make_bert_dual_encoder(tower, precision=args.precision)
+    if args.arch in lm_tower_archs():
+        tower = get_arch(args.arch).model_cfg
+        enc = make_lm_dual_encoder(tower, precision=args.precision)
+    else:
+        tower = bert_tower(args.arch)
+        if max(args.q_len, args.p_len) > tower.max_position:
+            raise SystemExit(
+                f"--q-len/--p-len exceed {args.arch}'s max_position "
+                f"{tower.max_position}"
+            )
+        enc = make_bert_dual_encoder(tower, precision=args.precision)
     tx = chain(
         clip_by_global_norm(cfg.grad_clip_norm),
         adamw(linear_warmup_linear_decay(args.lr, args.steps // 10, args.steps)),

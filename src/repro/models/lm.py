@@ -1,7 +1,11 @@
 """Decoder-only causal LM family (covers all five assigned LM architectures).
 
 Modern pre-norm transformer: RMSNorm, RoPE, GQA attention, SwiGLU FFN
-(or MoE FFN), optional QKV bias (qwen1.5), untied LM head.
+(or MoE FFN), optional QKV bias (qwen1.5), untied LM head. The
+DeepSeek-V3 block (Moonlight-16B-A3B) is the same skeleton with latent
+attention (``mla``, models/mla.py), ``first_dense`` leading dense layers
+before the scanned expert stack, and the dropless expert layer
+(``MoEConfig.dropless``); as a retriever tower it builds no LM head.
 
 Layers are scanned with stacked parameters so an 80-layer 110B-parameter
 model lowers to a compact HLO; remat policy is configurable. The LM loss is
@@ -24,7 +28,8 @@ import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.models.attention import attention, decode_attention
-from repro.models.moe import MoEConfig, init_moe, moe_ffn
+from repro.models.mla import MLAConfig, init_mla, mla
+from repro.models.moe import MoEConfig, init_moe, moe_dropless, moe_ffn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +47,9 @@ class LMConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None       # latent attention in place of GQA
+    first_dense: int = 0                  # leading dense layers (FFN d_ff)
+    head: bool = True                     # build the LM head (towers: no)
     # execution
     # per-arch declaration: LM towers default to bf16 compute (the presets in
     # configs/ override per size); resolve_precision turns this into a policy
@@ -58,8 +66,21 @@ class LMConfig:
     def dh(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
 
+    def with_precision(self, policy) -> "LMConfig":
+        """Bind a PrecisionPolicy (core/precision.py; instance or preset
+        name): params stored in ``param_dtype``, cast to ``compute_dtype``
+        where each is used; the router and the norms keep float32."""
+        from repro.core.precision import resolve_precision
+
+        policy = resolve_precision(policy)
+        return dataclasses.replace(
+            self, dtype=policy.compute_dtype, param_dtype=policy.param_dtype
+        )
+
     def param_count(self) -> int:
         d, dh = self.d_model, self.dh
+        if self.mla is not None:
+            return self._mla_param_count()
         attn = d * (self.n_heads * dh) * 2 + d * (self.n_kv_heads * dh) * 2
         if self.moe:
             ffn = d * self.moe.n_experts * self.moe.d_expert * 3 + d * self.moe.n_experts
@@ -68,6 +89,16 @@ class LMConfig:
         per_layer = attn + ffn + 2 * d
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         return self.n_layers * per_layer + emb + d
+
+    def _mla_param_count(self) -> int:
+        """Parameters held here: embedding (and head, if built), the dense
+        leading layers, the expert stack with its held experts, norms."""
+        d = self.d_model
+        attn = self.mla.param_count(d, self.n_heads) + 2 * d
+        dense = attn + 3 * d * self.d_ff
+        stack = attn + (self.moe.param_count(d) if self.moe else 3 * d * self.d_ff)
+        emb = self.vocab_size * d * (2 if self.head and not self.tie_embeddings else 1)
+        return self.first_dense * dense + (self.n_layers - self.first_dense) * stack + emb + d
 
     def active_param_count(self) -> int:
         """Activated parameters per token (MoE: top_k of n_experts)."""
@@ -88,6 +119,8 @@ class KVCache(NamedTuple):
 
 
 def init_lm(rng, cfg: LMConfig):
+    if cfg.mla is not None:
+        return _init_mla_lm(rng, cfg)
     d, dh, h, hk = cfg.d_model, cfg.dh, cfg.n_heads, cfg.n_kv_heads
     nl = cfg.n_layers
     ks = jax.random.split(rng, 12)
@@ -133,6 +166,73 @@ def init_lm(rng, cfg: LMConfig):
     return params
 
 
+def _init_mla_lm(rng, cfg: LMConfig):
+    """{"embed", "dense_layers", "layers", "final_norm"[, "lm_head"]}: the
+    ``first_dense`` leading layers and the expert stack, each stacked for
+    its own scan."""
+    d, pd = cfg.d_model, cfg.param_dtype
+    nd, nm = cfg.first_dense, cfg.n_layers - cfg.first_dense
+    ks = jax.random.split(rng, 8)
+
+    def dense_ffn(key, n):
+        kg, ku, kd = jax.random.split(key, 3)
+        f = cfg.d_ff
+        return {
+            "w_gate": (jax.random.normal(kg, (n, d, f)) * d ** -0.5).astype(pd),
+            "w_up": (jax.random.normal(ku, (n, d, f)) * d ** -0.5).astype(pd),
+            "w_down": (jax.random.normal(kd, (n, f, d)) * f ** -0.5).astype(pd),
+        }
+
+    def layers(key_attn, n, ffn):
+        return {
+            "ln1": jnp.ones((n, d), pd),
+            "ln2": jnp.ones((n, d), pd),
+            "attn": init_mla(key_attn, d, cfg.n_heads, cfg.mla, n, pd),
+            "ffn": ffn,
+        }
+
+    moe_ffn_p = (init_moe(ks[4], d, cfg.moe, nm, pd) if cfg.moe is not None
+                 else dense_ffn(ks[4], nm))
+    params = {
+        "embed": (jax.random.normal(ks[0], (cfg.vocab_size, d)) * 0.02).astype(pd),
+        "dense_layers": layers(ks[1], nd, dense_ffn(ks[2], nd)),
+        "layers": layers(ks[3], nm, moe_ffn_p),
+        "final_norm": jnp.ones((d,), pd),
+    }
+    if cfg.head and not cfg.tie_embeddings:
+        params["lm_head"] = (jax.random.normal(ks[5], (d, cfg.vocab_size)) * d ** -0.5).astype(pd)
+    return params
+
+
+def _ffn(cfg: LMConfig, fp, y, dense: bool):
+    """The block's feed-forward on normed input y (B, S, d): a dense SwiGLU
+    or the expert layer. Returns (out, aux, counters)."""
+    b, s, d = y.shape
+    dt = cfg.dtype
+    if dense or cfg.moe is None:
+        out = L.swiglu(y @ fp["w_gate"].astype(dt), y @ fp["w_up"].astype(dt)) @ fp[
+            "w_down"
+        ].astype(dt)
+        return out, {}, {}
+    if cfg.moe.dropless:
+        ff, counters = moe_dropless(fp, y.reshape(b * s, d), cfg.moe)
+        return ff.reshape(b, s, d), {}, counters
+    ff, aux = moe_ffn(fp, y.reshape(b * s, d), cfg.moe)
+    return ff.reshape(b, s, d), aux, {}
+
+
+def _mla_block(cfg: LMConfig, lp, x, cos, sin, *, dense: bool):
+    """One DeepSeek-V3 block: x + MLA(norm x), then + FFN(norm x).
+    Returns (x', counters)."""
+    y = L.rms_norm(lp["ln1"], x, eps=cfg.norm_eps)
+    with jax.named_scope("mla"):
+        x = x + mla(lp["attn"], y, cfg.mla, cfg.n_heads, cos, sin,
+                    dtype=cfg.dtype, eps=cfg.norm_eps)
+    y = L.rms_norm(lp["ln2"], x, eps=cfg.norm_eps)
+    ff, _, counters = _ffn(cfg, lp["ffn"], y, dense)
+    return x + ff, counters
+
+
 def _block(cfg: LMConfig, lp, x, cos, sin, *, kv_mask=None, causal=True):
     """One transformer block. lp: per-layer params (no leading L dim).
     x: (B, S, d). Returns (x', aux_metrics, (k, v))."""
@@ -166,15 +266,7 @@ def _block(cfg: LMConfig, lp, x, cos, sin, *, kv_mask=None, causal=True):
     x = x + (o.reshape(b, s, h * dh) @ ap["wo"].astype(dt))
 
     y = L.rms_norm(lp["ln2"], x, eps=cfg.norm_eps)
-    aux = {}
-    if cfg.moe is not None:
-        ff, aux = moe_ffn(lp["ffn"], y.reshape(b * s, d), cfg.moe)
-        ff = ff.reshape(b, s, d)
-    else:
-        fp = lp["ffn"]
-        ff = L.swiglu(y @ fp["w_gate"].astype(dt), y @ fp["w_up"].astype(dt)) @ fp[
-            "w_down"
-        ].astype(dt)
+    ff, aux, _ = _ffn(cfg, lp["ffn"], y, dense=False)
     x = x + ff
     return x, aux, (k, v)
 
@@ -191,8 +283,46 @@ def _remat_wrap(cfg: LMConfig, fn):
     raise ValueError(f"unknown remat policy {cfg.remat!r}")
 
 
+def _mla_backbone(params, cfg: LMConfig, tokens: jnp.ndarray):
+    """tokens (B, S) -> (final hidden states (B, S, d), counters): the
+    dense leading layers, then the expert stack, each one scan. Counters are
+    the expert layers', summed over layers except ``expert_slots``, which
+    stays per layer (n_layers - first_dense, n_held)."""
+    b, s = tokens.shape
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    rope = cfg.mla.qk_rope_head_dim
+    cos, sin = L.rotary_embedding(jnp.arange(s), rope, cfg.rope_theta, cfg.dtype)
+    cos = jnp.broadcast_to(cos, (b, s, rope // 2))
+    sin = jnp.broadcast_to(sin, (b, s, rope // 2))
+
+    def stack(x, layers, dense):
+        def layer_fn(x, lp):
+            return _mla_block(cfg, lp, x, cos, sin, dense=dense)
+
+        fn = _remat_wrap(cfg, layer_fn)
+        if cfg.scan_layers:
+            return jax.lax.scan(fn, x, layers)
+        n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        outs = []
+        for i in range(n):
+            x, c = fn(x, jax.tree_util.tree_map(lambda a: a[i], layers))
+            outs.append(c)
+        return x, jax.tree_util.tree_map(lambda *cs: jnp.stack(cs), *outs) if outs else {}
+
+    if cfg.first_dense:
+        x, _ = stack(x, params["dense_layers"], True)
+    x, per_layer = stack(x, params["layers"], False)
+    counters = {k: (v if k == "expert_slots" else v.sum(0)) for k, v in per_layer.items()}
+    return L.rms_norm(params["final_norm"], x, eps=cfg.norm_eps), counters
+
+
 def backbone(params, cfg: LMConfig, tokens: jnp.ndarray, *, collect_cache: bool = False):
     """tokens (B, S) -> hidden states (B, S, d) [+ stacked (k, v) per layer]."""
+    if cfg.mla is not None:
+        if collect_cache:
+            raise NotImplementedError("latent attention keeps no KV cache here")
+        x, _ = _mla_backbone(params, cfg, tokens)
+        return x, jnp.zeros((), jnp.float32), None
     b, s = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     pos = jnp.arange(s)
@@ -352,11 +482,20 @@ def decode_step(params, cfg: LMConfig, cache: KVCache, token: jnp.ndarray):
 
 
 def encode_pooled(params, cfg: LMConfig, tokens: jnp.ndarray, mask: Optional[jnp.ndarray] = None):
-    """LM-as-retriever embedding (GTR/E5 style): mean-pool final hidden states
-    over valid positions. Used when the paper's contrastive objective rides on
-    a causal-LM backbone."""
-    x, _, _ = backbone(params, cfg, tokens)
+    """LM-as-retriever embedding (GTR/E5 style; E5-Mistral, RepLLaMA):
+    mean-pool final hidden states over valid positions, summed in float32,
+    returned in the compute dtype. Used when the paper's contrastive
+    objective rides on a causal-LM backbone. Returns (pooled, the expert
+    layers' counters: empty for other towers)."""
+    if cfg.mla is not None:
+        x, counters = _mla_backbone(params, cfg, tokens)
+    else:
+        (x, _, _), counters = backbone(params, cfg, tokens), {}
+    xf = x.astype(jnp.float32)
     if mask is None:
-        return x.mean(axis=1)
-    m = mask.astype(x.dtype)[..., None]
-    return (x * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
+        pooled = xf.mean(axis=1)
+    else:
+        m = mask.astype(jnp.float32)[..., None]
+        pooled = (xf * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
+    pooled = pooled.astype(x.dtype)
+    return pooled, counters

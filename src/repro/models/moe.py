@@ -1,4 +1,15 @@
-"""Mixture-of-Experts FFN (token-choice top-k routing, capacity dispatch).
+"""Mixture-of-Experts FFN: token-choice top-k routing, two layers.
+
+``moe_dropless`` is the retriever towers' layer (DeepSeek-V3, arXiv:
+2412.19437 section 2.1.2): sigmoid scores, a selection-only bias,
+normalised and scaled top-k gates, shared experts, and only the
+experts this device holds (``MoEConfig.held``) computed, as grouped matrix
+products over contiguous expert groups (``jax.lax.ragged_dot``). It drops
+no token, whatever the skew: a representation never depends on what else
+is in the batch.
+
+``moe_ffn`` is the capacity-factor layer of the dry-run LM presets, which
+drops the tokens past an expert's capacity:
 
 TPU-native formulation (GShard/Switch style, as used by MaxText's "dropping"
 strategy): tokens are processed in groups; within a group a k-hot dispatch
@@ -16,7 +27,7 @@ which is one of the §Perf hillclimb levers.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,23 +51,134 @@ class MoEConfig:
     # all-reduce per iteration (measured 1.3 TiB wire/step on qwen3-moe).
     # scan mode remains for memory-constrained single-host debugging.
     vectorize_groups: bool = True
+    # the dropless layer (moe_dropless) and what it reads
+    dropless: bool = False
+    routed_scale: float = 1.0            # routed_scaling_factor
+    d_shared: int = 0                    # width of the shared experts, fused
+    held: Optional[Tuple[int, int]] = None  # (first, count) held here; None: all
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.n_experts)
+
+    def param_count(self, d_model: int) -> int:
+        """Parameters of one layer as held here (router, bias, held and
+        shared experts)."""
+        n_held = self.held_range[1]
+        bias = self.n_experts if self.dropless else 0
+        return (d_model * self.n_experts + bias + 3 * d_model * self.d_expert * n_held
+                + 3 * d_model * self.d_shared)
 
 
 def init_moe(rng, d_model: int, cfg: MoEConfig, n_layers: int, param_dtype=jnp.float32):
-    ks = jax.random.split(rng, 4)
+    """Stacked (n_layers, ...) weights of the held experts (all of them
+    unless ``cfg.held``), the router over every expert, the shared experts
+    where the config has them, and the dropless layer's selection bias."""
+    ks = list(jax.random.split(rng, 4)) + list(jax.random.split(jax.random.fold_in(rng, 1), 4))
     e, f = cfg.n_experts, cfg.d_expert
+    n_held = cfg.held_range[1]
 
     def stack(key, shape, scale_dim):
         return (
             jax.random.normal(key, (n_layers,) + shape) * (scale_dim ** -0.5)
         ).astype(param_dtype)
 
-    return {
+    p = {
         "router": stack(ks[0], (d_model, e), d_model),
-        "w_gate": stack(ks[1], (e, d_model, f), d_model),
-        "w_up": stack(ks[2], (e, d_model, f), d_model),
-        "w_down": stack(ks[3], (e, f, d_model), f),
+        "w_gate": stack(ks[1], (n_held, d_model, f), d_model),
+        "w_up": stack(ks[2], (n_held, d_model, f), d_model),
+        "w_down": stack(ks[3], (n_held, f, d_model), f),
     }
+    if cfg.dropless:
+        # the load-balancing bias; held at its seeded value (no gradient)
+        p["select_bias"] = stack(ks[4], (e,), 1.0) * 0.1
+    if cfg.d_shared:
+        p["shared"] = {
+            "w_gate": stack(ks[5], (d_model, cfg.d_shared), d_model),
+            "w_up": stack(ks[6], (d_model, cfg.d_shared), d_model),
+            "w_down": stack(ks[7], (cfg.d_shared, d_model), cfg.d_shared),
+        }
+    return p
+
+
+def route(router, bias, x, cfg: MoEConfig):
+    """(T, d) -> (gates (T, k) float32, experts (T, k) int32): the top-k of
+    the sigmoid scores plus the selection bias (``noaux_tc``), gated by the
+    unbiased scores, normalised over the k where the config says so, then
+    scaled by ``routed_scale``."""
+    scores = jax.nn.sigmoid(x.astype(jnp.float32) @ router.astype(jnp.float32))
+    choose = scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, experts = jax.lax.top_k(choose, cfg.top_k)
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.normalize_top_k:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return gates * cfg.routed_scale, experts
+
+
+def _grouped(lhs, rhs, group_sizes, valid):
+    """``ragged_dot`` over the buffer's routed rows. The chip's kernel
+    writes no row past the last group, so those rows, and their rows of
+    the products' cotangents, are masked on the way in and out."""
+    lhs = jnp.where(valid, lhs, 0)
+    return jnp.where(valid, jax.lax.ragged_dot(lhs, rhs, group_sizes), 0)
+
+
+def moe_dropless(params, x: jnp.ndarray, cfg: MoEConfig):
+    """x (T, d) -> (y (T, d), counters). Every token is routed over all
+    ``cfg.n_experts``; the slots that go to the held experts are sorted by
+    expert and computed as grouped products (three forward, each with its
+    two backward products), weighted by their gates and summed per token.
+    Slots to absent experts add nothing here: other devices hold them. The
+    shared experts are added once.
+
+    Counters (int32 sums): ``expert_slots`` (n_held,), the slots
+    routed to each held expert; ``absent_slots``, those routed elsewhere;
+    ``rows_routed``, the rows the grouped products had to compute, and
+    ``rows_buffer``, the rows of the static buffer that the gather, the
+    masks, the SwiGLU and the scatter span whatever the routing."""
+    t, d = x.shape
+    first, n_held = cfg.held_range
+    k = cfg.top_k
+    dt = x.dtype
+    with jax.named_scope("moe_router"):
+        gates, experts = route(params["router"], params["select_bias"], x, cfg)
+    with jax.named_scope("moe_dispatch"):
+        local = experts.reshape(-1) - first                      # (T*k,)
+        held = (local >= 0) & (local < n_held)
+        group = jnp.where(held, local, n_held)                   # absent last
+        group_sizes = jnp.zeros((n_held + 1,), jnp.int32).at[group].add(1)[:n_held]
+        # at most min(k, n_held) of a token's slots are held here: a buffer
+        # of that many rows a token holds every routing, however skewed
+        rows = t * min(k, n_held)
+        order = jnp.argsort(group, stable=True)[:rows]
+        token = order // k
+        xs = jnp.take(x, token, axis=0)
+        routed = group_sizes.sum()
+        # a row past the held slots computes nothing and gets no weight
+        valid = (jnp.arange(rows) < routed)[:, None]
+        w = jnp.where(valid[:, 0], gates.reshape(-1)[order], 0.0)
+    with jax.named_scope("moe_experts"):
+        h = swiglu(
+            _grouped(xs, params["w_gate"].astype(dt), group_sizes, valid),
+            _grouped(xs, params["w_up"].astype(dt), group_sizes, valid),
+        )
+        # the gate weighs the slot before the (linear) down projection
+        ys = _grouped(h * w[:, None].astype(dt), params["w_down"].astype(dt), group_sizes,
+                      valid)
+    with jax.named_scope("moe_combine"):
+        y = jnp.zeros((t, d), jnp.float32).at[token].add(ys)
+    if cfg.d_shared:
+        with jax.named_scope("moe_shared"):
+            sp = params["shared"]
+            y = y + (swiglu(x @ sp["w_gate"].astype(dt), x @ sp["w_up"].astype(dt))
+                     @ sp["w_down"].astype(dt)).astype(jnp.float32)
+    counters = {
+        "expert_slots": group_sizes,
+        "absent_slots": t * k - routed,
+        "rows_routed": routed,
+        "rows_buffer": jnp.int32(rows),
+    }
+    return y.astype(dt), counters
 
 
 def _capacity(group_size: int, cfg: MoEConfig) -> int:

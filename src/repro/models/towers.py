@@ -1,9 +1,11 @@
 """Dual-encoder wrappers binding backbones to the core DualEncoder interface.
 
 The paper's retriever is two BERTs ([CLS] pooling); the LM-retriever variant
-(GTR/E5 style) wraps a causal-LM backbone with mean pooling. Both produce
-``params = {"query": ..., "passage": ...}`` so the core methods' gradient
--norm diagnostics (Fig. 5) apply uniformly.
+(GTR/E5 style) wraps a causal-LM backbone with mean pooling. Two towers give
+``params = {"query": ..., "passage": ...}``; a shared tower gives
+``{"shared": ...}``, one backbone held once, which both sides encode with
+and whose gradient sums both sides' (core/types.py ``DualEncoder``). The
+gradient-norm diagnostics (Fig. 5) then give each side's contribution.
 """
 
 from __future__ import annotations
@@ -11,7 +13,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.core.precision import apply_compute_dtype
 from repro.core.types import DualEncoder
 from repro.models.bert import BertConfig, bert_encode, init_bert
 from repro.models.lm import LMConfig, encode_pooled, init_lm
@@ -36,23 +37,24 @@ def make_bert_dual_encoder(
 
     def init(rng):
         kq, kp = jax.random.split(rng)
-        q = init_bert(kq, cfg)
-        p = q if shared else init_bert(kp, cfg)
-        return {"query": q, "passage": p}
+        if shared:
+            return {"shared": init_bert(kq, cfg)}
+        return {"query": init_bert(kq, cfg), "passage": init_bert(kp, cfg)}
 
     def encode_query(params, batch):
         tokens, mask = _as_tokens(batch)
-        return bert_encode(params["query"], cfg, tokens, mask)
+        return bert_encode(params["shared" if shared else "query"], cfg, tokens, mask)
 
     def encode_passage(params, batch):
         tokens, mask = _as_tokens(batch)
-        return bert_encode(params["passage"], cfg, tokens, mask)
+        return bert_encode(params["shared" if shared else "passage"], cfg, tokens, mask)
 
     return DualEncoder(
         init=init,
         encode_query=encode_query,
         encode_passage=encode_passage,
         rep_dim=cfg.d_model,
+        shared=shared,
     )
 
 
@@ -60,29 +62,30 @@ def make_lm_dual_encoder(
     cfg: LMConfig, *, shared: bool = True, precision=None
 ) -> DualEncoder:
     """LM-as-retriever: one shared causal-LM backbone (the common modern
-    setup), mean pooling over valid positions. ``precision`` wraps the
-    encoder with the generic compute-dtype caster
-    (core/precision.apply_compute_dtype) — LMConfig carries its own dtype,
-    so the policy is applied at the DualEncoder boundary."""
+    setup: E5-Mistral, RepLLaMA), mean pooling over valid positions.
+    ``precision`` (a PrecisionPolicy or preset name) rebinds the backbone's
+    dtypes via ``LMConfig.with_precision``: fp32 masters, activations and
+    the pooled representations in the compute dtype. The update reads the
+    expert layers' counters through ``encode_counters``."""
+    if precision is not None:
+        cfg = cfg.with_precision(precision)
 
     def init(rng):
         kq, kp = jax.random.split(rng)
-        q = init_lm(kq, cfg)
-        p = q if shared else init_lm(kp, cfg)
-        return {"query": q, "passage": p}
+        if shared:
+            return {"shared": init_lm(kq, cfg)}
+        return {"query": init_lm(kq, cfg), "passage": init_lm(kp, cfg)}
 
-    def encode_query(params, batch):
+    def encode_counters(params, side, batch):
         tokens, mask = _as_tokens(batch)
-        return encode_pooled(params["query"], cfg, tokens, mask)
+        tower = params["shared" if shared else side]
+        return encode_pooled(tower, cfg, tokens, mask)
 
-    def encode_passage(params, batch):
-        tokens, mask = _as_tokens(batch)
-        return encode_pooled(params["passage"], cfg, tokens, mask)
-
-    enc = DualEncoder(
+    return DualEncoder(
         init=init,
-        encode_query=encode_query,
-        encode_passage=encode_passage,
+        encode_query=lambda params, batch: encode_counters(params, "query", batch)[0],
+        encode_passage=lambda params, batch: encode_counters(params, "passage", batch)[0],
         rep_dim=cfg.d_model,
+        shared=shared,
+        encode_counters=encode_counters,
     )
-    return enc if precision is None else apply_compute_dtype(enc, precision)

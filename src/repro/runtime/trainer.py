@@ -290,15 +290,13 @@ class Trainer:
         )
 
     def _log(self, step: int, metrics, dt: float) -> Dict[str, float]:
-        if isinstance(metrics, dict):
-            flat = {k: float(np.asarray(v)) for k, v in metrics.items()
-                    if np.ndim(v) == 0}
-        else:  # NamedTuple (StepMetrics)
-            flat = {
-                k: float(np.asarray(v))
-                for k, v in metrics._asdict().items()
-                if np.ndim(v) == 0
-            }
+        items = metrics if isinstance(metrics, dict) else metrics._asdict()
+        flat = {}
+        for k, v in items.items():
+            # a group of metrics (StepMetrics.tower) is logged by its keys
+            for kk, vv in (v.items() if isinstance(v, dict) else [(k, v)]):
+                if np.ndim(vv) == 0:
+                    flat[kk] = float(np.asarray(vv))
         flat["step"] = step
         flat["step_time_s"] = dt
         self.history.append(flat)

@@ -39,6 +39,31 @@ def make_mlp_encoder(dim_in: int = 16, dim_hidden: int = 32, dim_rep: int = 8) -
     )
 
 
+def mixed_precision(encoder: DualEncoder, policy) -> DualEncoder:
+    """The MLP towers under a PrecisionPolicy, as the model towers bind one
+    (``BertConfig.with_precision``, ``LMConfig.with_precision``): params
+    stored in ``param_dtype``, cast with the float inputs to
+    ``compute_dtype`` at each application, representations in it."""
+    from repro.core.precision import resolve_precision
+
+    policy = resolve_precision(policy)
+    ct = policy.compute_dtype
+
+    def cast(tree, dtype):
+        return jax.tree_util.tree_map(
+            lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+
+    def side(encode):
+        return lambda params, batch: encode(cast(params, ct), cast(batch, ct)).astype(ct)
+
+    return DualEncoder(
+        init=lambda rng: cast(encoder.init(rng), policy.param_dtype),
+        encode_query=side(encoder.encode_query),
+        encode_passage=side(encoder.encode_passage),
+        rep_dim=encoder.rep_dim,
+    )
+
+
 def make_batch(rng, batch_size: int, dim_in: int = 16, n_hard: int = 0) -> RetrievalBatch:
     kq, kp, kh = jax.random.split(rng, 3)
     # planted structure: positives correlated with queries so accuracy moves

@@ -30,7 +30,6 @@ from repro.core import (
     ContrastiveConfig,
     PrecisionPolicy,
     RetrievalBatch,
-    apply_compute_dtype,
     bank_bytes_per_device,
     build_step_program,
     contrastive_loss,
@@ -41,7 +40,7 @@ from repro.core.loss import DenseLossBackend, FusedLossBackend
 from repro.optim import adamw, chain, clip_by_global_norm, sgd
 from repro.optim.adamw import apply_updates
 
-from helpers import make_batch, make_mlp_encoder
+from helpers import make_batch, make_mlp_encoder, mixed_precision
 
 SOURCES = ["in_batch", "gathered", "dual_bank", "passage_bank"]
 STRATEGIES = ["direct", "scan", "rep_cache"]
@@ -76,7 +75,7 @@ def _run_trajectory(cfg, n_steps=3):
     policy = resolve_precision(cfg.precision)
     enc = make_mlp_encoder()
     if policy.name != "fp32":
-        enc = apply_compute_dtype(enc, policy)
+        enc = mixed_precision(enc, policy)
     tx = _tx()
     state = init_state(jax.random.PRNGKey(0), enc, tx, cfg)
     update = build_step_program(enc, tx, cfg).update

@@ -382,3 +382,50 @@ def test_contrastive_cell_serves_new_compositions():
         assert prog.static_info["xdev"] and prog.static_info["shard_banks"]
         out = jax.eval_shape(prog.fn, *prog.args)
         assert out is not None
+
+
+def _shared(enc):
+    """``enc``'s query tower as one backbone that both sides encode with."""
+    return enc._replace(
+        init=lambda rng: {"shared": enc.init(rng)["query"]},
+        encode_query=lambda p, x: enc.encode_query({"query": p["shared"]}, x),
+        encode_passage=lambda p, x: enc.encode_passage({"passage": p["shared"]}, x),
+        shared=True,
+    )
+
+
+@pytest.mark.parametrize("method", ["dpr", "contaccum", "contcache"])
+def test_shared_tower_is_one_copy_with_the_sum_of_both_gradients(method):
+    """A shared tower is stored once, in the parameters and in the
+    optimizer's state, and moves by the sum of what each side contributes:
+    under SGD its update is that of two towers that start equal, added. Its
+    grad_norm_query and grad_norm_passage are the norms of the two sides'
+    contributions, the two towers' own gradient norms."""
+    two = make_mlp_encoder()
+    one = _shared(two)
+    cfg = ContrastiveConfig(method=method, accumulation_steps=2, bank_size=8,
+                            grad_clip_norm=1e9)
+    tx = sgd(0.1)
+    p = two.init(jax.random.PRNGKey(0))["query"]
+    s1 = init_state(None, one, tx, cfg, params={"shared": p})
+    s2 = init_state(None, two, tx, cfg, params={"query": p, "passage": p})
+    u1 = jax.jit(build_step_program(one, tx, cfg).update)
+    u2 = jax.jit(build_step_program(two, tx, cfg).update)
+    for i in range(2):
+        batch = make_batch(jax.random.PRNGKey(10 + i), 8, n_hard=1)
+        before = s1.params["shared"]
+        s1, m1 = u1(s1, batch)
+        s2, m2 = u2(s2, batch)
+        for name in ("loss", "grad_norm_query", "grad_norm_passage"):
+            np.testing.assert_allclose(getattr(m1, name), getattr(m2, name), rtol=1e-5)
+        both = jax.tree_util.tree_map(lambda q, p_, b: q + p_ - b, s2.params["query"],
+                                      s2.params["passage"], before)
+        for a, b in zip(jax.tree_util.tree_leaves(s1.params["shared"]),
+                        jax.tree_util.tree_leaves(both)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+        # two towers that moved apart no longer stand for the shared one
+        s2 = s2._replace(params={"query": s1.params["shared"], "passage": s1.params["shared"]})
+    n_one = len(jax.tree_util.tree_leaves(p))
+    assert list(s1.params) == ["shared"]
+    assert len(jax.tree_util.tree_leaves(s1.params)) == n_one
+    assert len(jax.tree_util.tree_leaves(s1.opt_state)) <= len(jax.tree_util.tree_leaves(s2.opt_state)) // 2 + 1

@@ -172,3 +172,36 @@ def test_dpr_bert_base_contaccum_step_fits_one_v5e(one_chip, loss_impl, monkeypa
     assert resident < V5E_HBM_BYTES, resident
     if loss_impl == "fused":
         assert _kernel_count(compiled) >= 3
+
+
+def test_moonlight_contaccum_step_fits_one_v5e(one_chip):
+    """The train-moonlight-ep8 cell's update through the training driver's
+    own step builder: one shared Moonlight-16B-A3B backbone at one chip's
+    share (the dense layer and 4 expert layers, 8 of 64 experts held, 20,480
+    ids), N_total 128, N_local 32 (K 4), N_mem 2048, q_len 32, p_len 256,
+    one hard negative, bf16 compute and banks. The grouped expert products
+    lower to the chip's ragged-dot kernels, and the whole update, with its
+    fp32 masters, Adam moments and gradient accumulator, fits one chip."""
+    args = train.parse_args([
+        "--arch", "moonlight-16b-a3b-ep8", "--method", "contaccum",
+        "--total-batch", "128", "--local-batch", "32", "--bank", "2048",
+        "--q-len", "32", "--p-len", "256", "--precision", "bf16_banks",
+    ])
+    ts = train.build_step(args)
+    assert ts.cfg.accumulation_steps == 4 and ts.enc.shared
+    state = jax.eval_shape(
+        lambda: init_state(jax.random.PRNGKey(0), ts.enc, ts.tx, ts.cfg)
+    )
+    n_params = sum(x.size for x in jax.tree.leaves(state.params))
+    assert abs(n_params - 526.5e6) < 0.1e6, n_params
+    state = jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype), state)
+    b = args.total_batch
+    batch = RetrievalBatch(
+        query=_shape(one_chip, (b, args.q_len), jnp.int32),
+        passage_pos=_shape(one_chip, (b, args.p_len), jnp.int32),
+        passage_hard=_shape(one_chip, (b, 1, args.p_len), jnp.int32),
+    )
+    compiled = ts.update.lower(state, batch).compile()
+    assert "ragged-dot" in compiled.as_text()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 10e9 < peak < V5E_HBM_BYTES, peak
